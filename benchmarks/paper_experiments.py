@@ -363,6 +363,7 @@ def exp_session(n: int = 900, m: int = 3600, k: int = 4,
 
 _SHARDED_MIXED_SUBPROC = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"   # fake devices; never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(k)d"
 import json, sys, time
 sys.path.insert(0, %(src)r)
@@ -450,7 +451,9 @@ def exp_sharded_mixed(n: int = 400, m: int = 1600, k: int = 8,
     host devices so the one-fragment-per-device engine actually shards
     (the timing compares the same workload on both backends on the same
     hardware; on real accelerators the sharded localEval runs in
-    parallel instead of timeslicing one CPU)."""
+    parallel instead of timeslicing one CPU).  The child runs with
+    ``JAX_PLATFORMS=cpu``: every number it reports is a fake-CPU-device
+    count or CPU timing, never a chip measurement."""
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
     code = _SHARDED_MIXED_SUBPROC % dict(src=src, n=n, m=m, k=k, n_q=n_q)
@@ -468,6 +471,7 @@ def exp_sharded_mixed(n: int = 400, m: int = 1600, k: int = 8,
 
 _SCALEOUT_SUBPROC = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"   # fake devices; never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(d)d"
 import json, sys, time
 sys.path.insert(0, %(src)r)
@@ -546,7 +550,8 @@ def exp_scaleout(n: int = 400, m: int = 1600, d: int = 8,
     collectives at each packing factor, and asserts at every k that
     shard_map answers == vmap answers and that summed per-group
     ``QueryStats`` equal each group's one-collective wire (packing adds
-    zero traffic)."""
+    zero traffic).  The child runs with ``JAX_PLATFORMS=cpu``: its numbers
+    are fake-CPU-device counts and CPU timings, never chip measurements."""
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
     code = _SCALEOUT_SUBPROC % dict(src=src, d=d, n=n, m=m,
@@ -568,6 +573,7 @@ def exp_scaleout(n: int = 400, m: int = 1600, d: int = 8,
 
 _CHAOS_SUBPROC = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"   # fake devices; never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(k)d"
 import json, sys, time
 sys.path.insert(0, %(src)r)
@@ -676,7 +682,9 @@ def exp_chaos(n: int = 48, m: int = 128, k: int = 8, rounds: int = 12,
     drain time over the round's queries), the request success rate, and
     the retry/rollback/degraded counters — and replays every applied
     delta through a host oracle to assert all answered results are exact
-    despite the injected failures."""
+    despite the injected failures.  The child runs with
+    ``JAX_PLATFORMS=cpu``: its latencies are fake-CPU-device timings,
+    never chip measurements."""
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
     tests = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",

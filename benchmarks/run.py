@@ -15,6 +15,8 @@ import json
 import sys
 import traceback
 
+from repro.compile_cache import use_compile_cache
+
 from . import paper_experiments as pe
 from .exp_async_serve import exp_async_serve
 from .exp_mvcc import exp_mvcc
@@ -32,6 +34,7 @@ def _emit(section: str, rows):
 
 
 def main() -> None:
+    use_compile_cache()
     fast = "--fast" in sys.argv
     scale = 0.25 if fast else 1.0
     suffix = ".fast.json" if fast else ".json"

@@ -1,11 +1,13 @@
 """CLI: ``python -m repro.analysis --all`` — run every static pass and
 exit non-zero on violations.  See DESIGN.md Sec. 10.
 
-The HLO pass lowers the real sharded programs and needs 8 host devices,
-but ``python -m repro.analysis`` imports the ``repro`` package (and with
-it the XLA backend) before this module runs — too late for
-``XLA_FLAGS``.  When the backend came up with fewer devices, the CLI
-re-execs itself once with the flag set in the child's environment.
+The HLO pass lowers the real sharded programs on 8 fake CPU devices.
+Whether this process can host that pass is decided from its environment
+alone, before any JAX backend starts: asking JAX for its devices would
+start the default backend, and on a TPU host that takes the chip, which a
+child could then not share.  Unless the process was started on the CPU
+platform with the fake-device flag, the CLI re-execs itself once with
+``JAX_PLATFORMS=cpu`` and the flag in the child's environment.
 """
 import argparse
 import json
@@ -13,23 +15,24 @@ import os
 import subprocess
 import sys
 
-_RESPAWN_SENTINEL = "_REPRO_ANALYSIS_RESPAWNED"
 _DEVICE_FLAG = "--xla_force_host_platform_device_count=8"
 
 
 def _ensure_devices(argv, min_devices=8):
-    """Return None if enough devices are visible, else the exit code of a
-    respawned child that has ``XLA_FLAGS`` set before Python starts."""
-    import jax
-    if jax.local_device_count() >= min_devices:
-        return None
-    if os.environ.get(_RESPAWN_SENTINEL):
+    """Return None if this process was started for the HLO pass (CPU
+    platform, fake-device flag set), else the exit code of a child
+    started that way."""
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and _DEVICE_FLAG in os.environ.get("XLA_FLAGS", "")):
+        import jax
+        if jax.local_device_count() >= min_devices:
+            return None
         print(f"error: {jax.local_device_count()} device(s) visible even "
               f"under {_DEVICE_FLAG}", file=sys.stderr)
         return 2
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _DEVICE_FLAG).strip()
-    env[_RESPAWN_SENTINEL] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.call(
         [sys.executable, "-m", "repro.analysis", *argv], env=env)
 

@@ -141,9 +141,8 @@ def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
     if cache is None:
         # jnp.array (copy=True), not asarray: see refresh_device_arrays.
         arrs = {k: jnp.array(v) for k, v in fr.arrays.items()}
-        front = jax.vmap(functools.partial(
-            engine.local_frontier_reach, n_max=fr.n_max))(
-            arrs["esrc"], arrs["edst"], arrs["src_local"])   # [k, S, n+1]
+        front = _each_fragment(engine.local_frontier_reach, fr.n_max,
+                               arrs)                        # [k, S, n+1]
         bl = _boundary_rows(fr, front, False, lambda ref, v: ref.max(v))
         D0 = _gather_boundary_matrix(fr, bl, fill=False)
         C = bes.bool_closure(D0, use_pallas=use_pallas)
@@ -151,16 +150,24 @@ def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
                            part_b=fr.boundary_owner())
         fr.rvset_cache = cache
     if with_dist and cache.bl_dist is None:
-        arrs = cache.arrays
-        front = jax.vmap(functools.partial(
-            engine.local_frontier_dist, n_max=fr.n_max))(
-            arrs["esrc"], arrs["edst"], arrs["src_local"])
+        front = _each_fragment(engine.local_frontier_dist, fr.n_max,
+                               cache.arrays)
         bl_d = _boundary_rows(fr, front, jnp.int32(INF),
                               lambda ref, v: ref.min(v))
         W0 = _gather_boundary_matrix(fr, bl_d, fill=INF)
         cache.bl_dist = bl_d
         cache.dist_closure = bes.tropical_closure(W0, use_pallas=use_pallas)
     return cache
+
+
+def _each_fragment(frontier_fn, n_max: int, arrs) -> jax.Array:
+    """All-sources fixpoint of every fragment, one fragment at a time:
+    [k, S, n_max+1].  A ``lax.map``, not a vmap, so the fixpoint's [S, E]
+    message buffers exist for one fragment at a time — vmapped over all k
+    fragments of a full-size graph they exceed a chip's memory."""
+    return jax.lax.map(
+        lambda a: frontier_fn(*a, n_max=n_max),
+        (arrs["esrc"], arrs["edst"], arrs["src_local"]))
 
 
 def _gather_boundary_matrix(fr: Fragmentation, bl, fill):
@@ -281,7 +288,9 @@ def local_stage_rpq(esrc, edst, src_local, src_row, tgt_local, labels, gids,
         esrc, edst, src_local, src_row, tgt_local, labels, gids,
         q_labels, q_trans, jnp.int32(n_max), jnp.int32(n_max),
         jnp.int32(NO_NODE), jnp.int32(NO_NODE), n_max=n_max, B=B)
-    d0 = rloc.reshape(B, Q, B, Q)[:nb, :, :nb, :].reshape(nb * Q, nb * Q)
+    # boundary rows/cols (b, q) sit at b*Q + q, so b < nb is a prefix: a
+    # plain 2-D slice (a [B, Q, B, Q] view would pad Q to 128 TPU lanes)
+    d0 = rloc[:nb * Q, :nb * Q]
     f = jax.vmap(lambda sl, sg, tg: engine.single_source_regular(
         esrc, edst, labels, gids, q_labels, q_trans, sl, q_start, sg, tg,
         n_max=n_max))(s_slot, s_gids, t_gids)              # [N, n+1, Q]
@@ -298,55 +307,73 @@ def local_stage_rpq(esrc, edst, src_local, src_row, tgt_local, labels, gids,
 
 # -- packed variants: one device owning SEVERAL fragments (k >> d) ----------
 #
-# Each wrapper vmaps its per-fragment stage over the leading owned-fragments
-# axis (fpd) and merges the contributions on-device — OR for the Boolean
-# kinds, min for the tropical one.  The merge is exact for the same reason
-# the cross-device collective is: every d0/sb row and tc column is computed
-# by exactly one fragment (the others contribute the semiring zero), and
-# ownership stays disjoint whether fragments sit on different devices or
-# share one.  Inert pad fragments (pad-only edge lists, all-false ownership
-# masks, absent s/t slots) contribute zeros/INF and their propagations
-# converge in zero while_loop iterations, so short devices cost nothing.
+# Each wrapper runs its per-fragment stage over the leading owned-fragments
+# axis (fpd) one fragment at a time and folds the contributions on-device —
+# OR for the Boolean kinds, min for the tropical one.  The merge is exact
+# for the same reason the cross-device collective is: every d0/sb row and
+# tc column is computed by exactly one fragment (the others contribute the
+# semiring zero), and ownership stays disjoint whether fragments sit on
+# different devices or share one.  Inert pad fragments (pad-only edge
+# lists, all-false ownership masks, absent s/t slots) contribute
+# zeros/INF and their propagations converge in zero while_loop iterations,
+# so short devices cost nothing.  A scan rather than a vmap: the stages'
+# propagation buffers then exist for one fragment at a time, which is what
+# lets a full-size fragment stack fit a chip's memory.
+
+def _fold_owned(stage, owned, zero, axis_name: str):
+    """``stage(*owned_i)`` for every owned fragment ``i`` (each array in
+    ``owned`` has a leading [fpd] axis), folded elementwise with the
+    semiring sum whose identity is ``zero`` (False: OR; INF: min).  Runs
+    inside shard_map over ``axis_name``, where the fold's carry must be
+    device-varying like the data it accumulates."""
+    merge = jnp.logical_or if zero is False else jnp.minimum
+    out = jax.eval_shape(stage, *(x[0] for x in owned))
+    init = jax.lax.pcast(tuple(jnp.full(o.shape, zero, o.dtype)
+                               for o in out), axis_name, to="varying")
+
+    def step(acc, frag):
+        return tuple(merge(a, o) for a, o in zip(acc, stage(*frag))), None
+
+    return jax.lax.scan(step, init, tuple(owned))[0]
+
 
 def local_stage_reach_packed(esrc, edst, src_local, s_slot, t_slot, srcidx,
-                             own, tgt_mine, *, n_max: int):
+                             own, tgt_mine, *, n_max: int, axis_name: str):
     """:func:`local_stage_reach` for a device owning ``fpd`` fragments —
     every argument gains a leading ``[fpd, ...]`` axis; the returned
-    ``(d0, sb, direct, tc)`` are OR-merged over it (shapes as unpacked)."""
-    d0, sb, direct, tc = jax.vmap(
-        functools.partial(local_stage_reach, n_max=n_max))(
-        esrc, edst, src_local, s_slot, t_slot, srcidx, own, tgt_mine)
-    return (jnp.any(d0, axis=0), jnp.any(sb, axis=0),
-            jnp.any(direct, axis=0), jnp.any(tc, axis=0))
+    ``(d0, sb, direct, tc)`` are OR-merged over it (shapes as unpacked).
+    ``axis_name``: the shard_map mesh axis this runs under."""
+    return _fold_owned(
+        functools.partial(local_stage_reach, n_max=n_max),
+        (esrc, edst, src_local, s_slot, t_slot, srcidx, own, tgt_mine),
+        False, axis_name)
 
 
 def local_stage_dist_packed(esrc, edst, src_local, s_slot, t_slot, srcidx,
-                            own, tgt_mine, *, n_max: int):
+                            own, tgt_mine, *, n_max: int, axis_name: str):
     """Tropical twin of :func:`local_stage_reach_packed`: min-merge over
     the owned-fragments axis (non-owners ship INF, the tropical zero)."""
-    w0, sb, direct, tc = jax.vmap(
-        functools.partial(local_stage_dist, n_max=n_max))(
-        esrc, edst, src_local, s_slot, t_slot, srcidx, own, tgt_mine)
-    return (jnp.min(w0, axis=0), jnp.min(sb, axis=0),
-            jnp.min(direct, axis=0), jnp.min(tc, axis=0))
+    return _fold_owned(
+        functools.partial(local_stage_dist, n_max=n_max),
+        (esrc, edst, src_local, s_slot, t_slot, srcidx, own, tgt_mine),
+        int(INF), axis_name)
 
 
 def local_stage_rpq_packed(esrc, edst, src_local, src_row, tgt_local, labels,
                            gids, q_labels, q_trans, q_start, s_slot, t_slot,
                            s_gids, t_gids, local_b, mine, *, n_max: int,
-                           B: int):
+                           B: int, axis_name: str):
     """:func:`local_stage_rpq` over the owned-fragments axis.  Per-fragment
     arguments carry ``[fpd, ...]``; the automaton (``q_*``), the pair gids
     and ``local_b`` stay replicated."""
-    d0, sb, direct, tc = jax.vmap(
-        functools.partial(local_stage_rpq, n_max=n_max, B=B),
-        in_axes=(0, 0, 0, 0, 0, 0, 0, None, None, None, 0, 0, None, None,
-                 None, 0))(
-        esrc, edst, src_local, src_row, tgt_local, labels, gids,
-        q_labels, q_trans, q_start, s_slot, t_slot, s_gids, t_gids,
-        local_b, mine)
-    return (jnp.any(d0, axis=0), jnp.any(sb, axis=0),
-            jnp.any(direct, axis=0), jnp.any(tc, axis=0))
+    def stage(es, ed, sl, sr, tl, lab, gid, ss, ts, mn):
+        return local_stage_rpq(es, ed, sl, sr, tl, lab, gid, q_labels,
+                               q_trans, q_start, ss, ts, s_gids, t_gids,
+                               local_b, mn, n_max=n_max, B=B)
+
+    return _fold_owned(stage, (esrc, edst, src_local, src_row, tgt_local,
+                               labels, gids, s_slot, t_slot, mine), False,
+                       axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -486,20 +513,23 @@ def product_closure(fr: Fragmentation, qa: QueryAutomaton,
     arrs = cache.arrays
     q_labels = jnp.asarray(qa.state_labels)
     q_trans = jnp.asarray(qa.trans)
-    k, n_max, B, Q = fr.k, fr.n_max, fr.B, qa.n_states
-    no_slot = jnp.full(k, n_max, jnp.int32)
-    local = jax.vmap(
-        lambda es, ed, sl, sr, tl, lab, gid, sloc, tloc:
-        engine.local_eval_regular(es, ed, sl, sr, tl, lab, gid,
-                                  q_labels, q_trans, sloc, tloc,
-                                  jnp.int32(NO_NODE), jnp.int32(NO_NODE),
-                                  n_max=n_max, B=B))
-    rlocs = local(arrs["esrc"], arrs["edst"], arrs["src_local"],
-                  arrs["src_row"], arrs["tgt_local"], arrs["labels"],
-                  arrs["gids"], no_slot, no_slot)
-    D = jnp.any(rlocs, axis=0)                              # [(B*Q), (B*Q)]
+    n_max, B, Q = fr.n_max, fr.B, qa.n_states
+
+    def fold(D, frag):
+        # one fragment's product rvset at a time, OR-ed into the matrix:
+        # the [S, Q, E, Q] propagation buffers of all k fragments at once
+        # would not fit a chip at full size
+        rloc = engine.local_eval_regular(
+            *frag, q_labels, q_trans, jnp.int32(n_max), jnp.int32(n_max),
+            jnp.int32(NO_NODE), jnp.int32(NO_NODE), n_max=n_max, B=B)
+        return D | rloc, None
+
+    D, _ = jax.lax.scan(fold, jnp.zeros((B * Q, B * Q), bool),
+                        tuple(arrs[name] for name in (
+                            "esrc", "edst", "src_local", "src_row",
+                            "tgt_local", "labels", "gids")))
     nb = fr.n_boundary
-    D = D.reshape(B, Q, B, Q)[:nb, :, :nb, :].reshape(nb * Q, nb * Q)
+    D = D[:nb * Q, :nb * Q]          # the boundary rows/cols: a prefix
     C = bes.bool_closure(D, use_pallas=use_pallas)
     # bound the per-automaton cache: each closure is (nb*Q)^2 bools, and a
     # server facing user-supplied regexes must not grow without limit.

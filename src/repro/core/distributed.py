@@ -32,7 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import cache as _cache
 from . import engine
@@ -40,18 +40,6 @@ from ..kernels.bitpack_ops.ops import pack_payload, unpack_payload
 from .automaton import QueryAutomaton
 from .bes import bool_closure, tropical_closure
 from .fragments import Fragmentation, Placement, query_slots
-
-# jax.shard_map moved to the top level after 0.4.x; support both.  The
-# experimental version cannot prove replication through while loops, so it
-# additionally needs check_rep=False (the engine's fixpoints are loops).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, **kwargs):
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_compat(f, **kwargs)
 
 FRAG_AXIS = "frag"
 
@@ -95,7 +83,7 @@ def dis_reach_sharded(fr: Fragmentation, s: int, t: int,
                       "s_local", "t_local"))
     tgt_cols, src_rows, bt = _answer_masks(fr, t)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=(P(), P()))
     def run(esrc, edst, src_local, src_row, tgt_local, s_local, t_local):
         rloc = engine.local_eval_reach(
@@ -149,7 +137,7 @@ def dis_rpq_sharded(fr: Fragmentation, s: int, t: int, qa: QueryAutomaton,
     specs = _specs()
     in_specs = tuple(specs[k] for k in names)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
     def run(esrc, edst, src_local, src_row, tgt_local, labels, gids,
             s_local, t_local):
@@ -178,7 +166,7 @@ def lower_reach_hlo(fr: Fragmentation, s: int, t: int,
     in_specs = tuple(specs[k] for k in names)
     tgt_cols, src_rows, _ = _answer_masks(fr, t)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
     def run(esrc, edst, src_local, src_row, tgt_local, s_local, t_local):
         rloc = engine.local_eval_reach(
@@ -258,13 +246,14 @@ def _pack_rows(arr: np.ndarray, perm: np.ndarray, pad) -> np.ndarray:
 def _batch_reach_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int, N: int):
     in_specs = tuple(P(FRAG_AXIS) for _ in range(8))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
     def run(esrc, edst, src_local, tgt_local, s_slot, t_slot, srcidx, own):
         # each arg arrives [fpd, ...]: this device's owned fragments
         d0, sb, direct, tc = _cache.local_stage_reach_packed(
             esrc, edst, src_local, s_slot, t_slot,
-            srcidx, own, tgt_local[:, :nb], n_max=n_max)
+            srcidx, own, tgt_local[:, :nb], n_max=n_max,
+            axis_name=FRAG_AXIS)
         payload = jnp.concatenate([
             jnp.concatenate([d0, jnp.zeros((nb, 1), bool)], axis=1),
             jnp.concatenate([sb, direct[:, None]], axis=1),
@@ -284,12 +273,13 @@ def _batch_reach_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int, N: int):
 def _batch_dist_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int, N: int):
     in_specs = tuple(P(FRAG_AXIS) for _ in range(8))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
     def run(esrc, edst, src_local, tgt_local, s_slot, t_slot, srcidx, own):
         w0, sb, direct, tc = _cache.local_stage_dist_packed(
             esrc, edst, src_local, s_slot, t_slot,
-            srcidx, own, tgt_local[:, :nb], n_max=n_max)
+            srcidx, own, tgt_local[:, :nb], n_max=n_max,
+            axis_name=FRAG_AXIS)
         inf_b = jnp.full((nb, 1), engine.INF, jnp.int32)
         inf_n = jnp.full((N, 1), engine.INF, jnp.int32)
         payload = jnp.concatenate([
@@ -317,7 +307,7 @@ def _batch_rpq_jitted(mesh: Mesh, nb: int, n_max: int, B: int, Q: int,
     in_specs = tuple(P(FRAG_AXIS) for _ in range(10)) + \
         tuple(P() for _ in range(5))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
     def run(esrc, edst, src_local, src_row, tgt_local, labels, gids,
             s_slot, t_slot, mine, q_labels, q_trans, s_gids, t_gids,
@@ -326,7 +316,7 @@ def _batch_rpq_jitted(mesh: Mesh, nb: int, n_max: int, B: int, Q: int,
             esrc, edst, src_local, src_row, tgt_local,
             labels, gids, q_labels, q_trans, jnp.int32(q_start),
             s_slot, t_slot, s_gids, t_gids, local_b, mine,
-            n_max=n_max, B=B)
+            n_max=n_max, B=B, axis_name=FRAG_AXIS)
         payload = jnp.concatenate([
             jnp.concatenate([d0, jnp.zeros((side, 1), bool)], axis=1),
             jnp.concatenate([sb, direct[:, None]], axis=1),
@@ -375,20 +365,33 @@ def _array_pads(fr: Fragmentation) -> dict:
 _UPLOAD_MEMO_CAP = 4
 
 
-def _device_inputs(fr: Fragmentation, placement: Placement) -> dict:
+def _sharded(x: np.ndarray, mesh: Mesh) -> jax.Array:
+    """Upload a device-major packed array straight into its shards: device
+    ``i`` receives only rows ``[i*fpd, (i+1)*fpd)``, so the full stack never
+    lands on one device before the jitted call reshards it."""
+    return jax.device_put(x, NamedSharding(mesh, P(FRAG_AXIS)))
+
+
+def _replicated(x: np.ndarray, mesh: Mesh) -> jax.Array:
+    return jax.device_put(x, NamedSharding(mesh, P()))
+
+
+def _device_inputs(fr: Fragmentation, placement: Placement,
+                   mesh: Mesh) -> dict:
     """Query-independent device uploads for the batched sharded engines —
     the fragment arrays plus the boundary-ownership gathers, packed into
-    the placement's device-major [d*fpd, ...] layout — memoized in a small
-    per-Fragmentation LRU keyed on ``(fr.arrays_version, placement)`` so
-    steady-state batches skip the host-to-device copy of the edge lists
-    entirely; any ``apply_delta``/``rebuild`` (which mutates the host
-    arrays in place and bumps the version) starts a fresh entry, as does
-    switching placements.  Several keys stay live so MVCC versions and
-    alternate placements don't thrash each other's uploads."""
+    the placement's device-major [d*fpd, ...] layout and placed shard by
+    shard on ``mesh`` — memoized in a small per-Fragmentation LRU keyed on
+    ``(fr.arrays_version, placement, mesh)`` so steady-state batches skip
+    the host-to-device copy of the edge lists entirely; any
+    ``apply_delta``/``rebuild`` (which mutates the host arrays in place and
+    bumps the version) starts a fresh entry, as does switching placements.
+    Several keys stay live so MVCC versions and alternate placements don't
+    thrash each other's uploads."""
     memos = fr.__dict__.get("_sharded_device_inputs")
     if memos is None:
         memos = fr.__dict__["_sharded_device_inputs"] = OrderedDict()
-    key = (fr.arrays_version, placement.cache_key())
+    key = (fr.arrays_version, placement.cache_key(), mesh)
     memo = memos.get(key)
     if memo is not None:
         memos.move_to_end(key)
@@ -401,12 +404,12 @@ def _device_inputs(fr: Fragmentation, placement: Placement) -> dict:
     memo = dict(
         version=fr.arrays_version, placement=placement.cache_key(),
         perm=perm,
-        arrs={key: jnp.asarray(_pack_rows(v, perm, pads[key]))
+        arrs={key: _sharded(_pack_rows(v, perm, pads[key]), mesh)
               for key, v in fr.arrays.items()},
-        srcidx=jnp.asarray(_pack_rows(srcidx, perm, fr.s_max - 1)),
-        own=jnp.asarray(_pack_rows(own, perm, False)),
-        mine=jnp.asarray(_pack_rows(mine, perm, False)),
-        local_b=jnp.asarray(fr.boundary_local()))
+        srcidx=_sharded(_pack_rows(srcidx, perm, fr.s_max - 1), mesh),
+        own=_sharded(_pack_rows(own, perm, False), mesh),
+        mine=_sharded(_pack_rows(mine, perm, False), mesh),
+        local_b=_replicated(fr.boundary_local(), mesh))
     memos[key] = memo
     while len(memos) > _UPLOAD_MEMO_CAP:
         memos.popitem(last=False)
@@ -433,10 +436,10 @@ def _batch_sharded_program(fr: Fragmentation, pairs: np.ndarray, kind: str,
     s_slots = np.full((k, N), n_max, dtype=np.int32)
     s_slots[fr.part[ss], np.arange(N)] = fr.owner_local[ss]
     t_slots = fr.slot_index()[tt, :].T.copy()              # [k, N]
-    dev = _device_inputs(fr, placement)
+    dev = _device_inputs(fr, placement, mesh)
     perm, fpd = dev["perm"], placement.fpd
-    s_slots = jnp.asarray(_pack_rows(s_slots, perm, n_max))
-    t_slots = jnp.asarray(_pack_rows(t_slots, perm, n_max))
+    s_slots = _sharded(_pack_rows(s_slots, perm, n_max), mesh)
+    t_slots = _sharded(_pack_rows(t_slots, perm, n_max), mesh)
     arrs = dev["arrs"]
     if kind == "rpq":
         run = _batch_rpq_jitted(mesh, fr.n_boundary, n_max, fr.B,
@@ -444,9 +447,10 @@ def _batch_sharded_program(fr: Fragmentation, pairs: np.ndarray, kind: str,
         args = (arrs["esrc"], arrs["edst"], arrs["src_local"],
                 arrs["src_row"], arrs["tgt_local"], arrs["labels"],
                 arrs["gids"], s_slots, t_slots,
-                dev["mine"], jnp.asarray(qa.state_labels),
-                jnp.asarray(qa.trans), jnp.asarray(ss.astype(np.int32)),
-                jnp.asarray(tt.astype(np.int32)), dev["local_b"])
+                dev["mine"], _replicated(qa.state_labels, mesh),
+                _replicated(qa.trans, mesh),
+                _replicated(ss.astype(np.int32), mesh),
+                _replicated(tt.astype(np.int32), mesh), dev["local_b"])
         return run, args
     jitted = {"reach": _batch_reach_jitted, "dist": _batch_dist_jitted}
     run = jitted[kind](mesh, fr.n_boundary, n_max, fpd, N)
@@ -575,7 +579,7 @@ def _update_rows_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int):
     per changed-row bucket shape, so steady-state deltas never retrace."""
     in_specs = tuple(P(FRAG_AXIS) for _ in range(6))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=(P(), P(FRAG_AXIS)))
     def run(esrc, edst, init, srcidx, own, tgt_local):
         # [fpd, ...] per device: resume every owned fragment's fixpoint
@@ -605,11 +609,11 @@ def _update_rows_program(fr: Fragmentation, warm_init: np.ndarray,
                          placement: Placement):
     perm = placement.perm()
     srcidx, own = _changed_row_inputs(fr, row_ids)
-    dev = _device_inputs(fr, placement)
+    dev = _device_inputs(fr, placement, mesh)
     arrs = (dev["arrs"]["esrc"], dev["arrs"]["edst"],
-            jnp.asarray(_pack_rows(np.asarray(warm_init), perm, False)),
-            jnp.asarray(_pack_rows(srcidx, perm, fr.s_max - 1)),
-            jnp.asarray(_pack_rows(own, perm, False)),
+            _sharded(_pack_rows(np.asarray(warm_init), perm, False), mesh),
+            _sharded(_pack_rows(srcidx, perm, fr.s_max - 1), mesh),
+            _sharded(_pack_rows(own, perm, False), mesh),
             dev["arrs"]["tgt_local"])
     return (_update_rows_jitted(mesh, fr.n_boundary, fr.n_max,
                                 placement.fpd), arrs)
@@ -635,17 +639,20 @@ def update_rows_sharded(fr: Fragmentation, warm_init: np.ndarray,
     The ONE collective ships only the *changed* bitpacked rows —
     ``len(row_ids) x ceil(nb/32)`` uint32 words, not the whole matrix.
 
-    Returns ``(rows, frontiers)``: the merged [r, nb] changed rows
-    (replicated) and the per-fragment [k, S, n_max+1] frontiers (sharded
-    outputs unpacked from the device-major layout, no extra
-    communication).
+    Returns ``(rows, frontiers)``: the merged [r, nb] changed rows and the
+    per-fragment [k, S, n_max+1] frontiers (sharded outputs unpacked from
+    the device-major layout, no extra communication), both on the default
+    device, where the host rvset cache they update lives.
     """
     mesh, placement = _resolve_placement(fr, mesh, placement)
     run, arrs = _update_rows_program(fr, warm_init, row_ids, mesh,
                                      placement)
     rows, fronts = run(*arrs)
+    # both feed the host rvset cache, which lives on one device: a program
+    # mixing them with it while they still span the mesh would have to
+    # partition the cache's Pallas kernels, which Mosaic cannot do
     fronts = _unpack_rows(np.asarray(fronts), placement.perm(), fr.k)
-    return rows, jnp.asarray(fronts)
+    return jnp.asarray(np.asarray(rows)), jnp.asarray(fronts)
 
 
 def lower_update_hlo(fr: Fragmentation, warm_init: np.ndarray,

@@ -35,8 +35,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-INF = jnp.int32(1 << 29)
+INF = np.int32(1 << 29)   # numpy: importing must not start a backend
 
 
 class QueryStats(NamedTuple):

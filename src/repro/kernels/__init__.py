@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the semiring hot spots the engine runs on a TPU:
+or-and (``bool_matmul``), min-plus (``tropical_matmul``) and the
+bit-packed or-and (``bitpack_ops``)."""
+import jax
+
+
+def out_vma(*operands) -> frozenset:
+    """The mesh axes a kernel's output varies over: those its operands
+    vary over.  Inside ``shard_map`` (which checks this) a ``pallas_call``
+    must declare it on its ``out_shape``; outside, it is empty."""
+    return frozenset().union(*(jax.typeof(x).vma for x in operands))

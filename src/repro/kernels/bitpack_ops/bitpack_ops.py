@@ -7,9 +7,10 @@ closure working set shrink 32x, and the or-and contraction becomes
 
     C[i, j] = OR_w ( Apacked[i, w] AND Bpacked[w, j] ) != 0
 
-— pure VPU bitwise ops, 32 contraction steps per loaded word.  The closure
-becomes memory-bound-optimal at the cost of leaving the MXU idle; see
-EXPERIMENTS.md §Perf for the crossover vs ``bool_matmul``.
+— pure VPU bitwise ops, 32 contraction steps per loaded word, leaving the
+MXU idle.  The engine uses the packing for its collective payloads
+(``ops.pack_payload``); this kernel is not on the served path, and its
+crossover against ``bool_matmul`` has not been measured on a chip.
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import out_vma
 
 
 def pack_rows(a: jax.Array) -> jax.Array:
@@ -53,29 +56,32 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int, cw: int):
 
     a = a_ref[...]                       # [bm, bw] uint32
     b = b_ref[...]                       # [bw, bn] uint32
-    bm, bw = a.shape
-    bn = b.shape[1]
-
-    def chunk(c, acc):
-        a_c = jax.lax.dynamic_slice(a, (0, c * cw), (bm, cw))
-        b_c = jax.lax.dynamic_slice(b, (c * cw, 0), (cw, bn))
-        hit = (a_c[:, :, None] & b_c[None, :, :]) != 0    # [bm, cw, bn]
-        return acc | jnp.any(hit, axis=1)
-
-    acc_ref[...] = jax.lax.fori_loop(0, bw // cw, chunk, acc_ref[...])
+    bw = a.shape[1]
+    # one packed word per step: broadcast a's word column along the lanes
+    # and b's word row along the sublanes (static unroll: the TPU lowering
+    # has no value-level dynamic slice); cw words OR into a partial hit
+    # before it meets the accumulator
+    acc = acc_ref[...]
+    for c0 in range(0, bw, cw):
+        hit = a[:, c0:c0 + 1] & b[c0:c0 + 1, :]           # [bm, bn]
+        for c in range(c0 + 1, c0 + cw):
+            hit = hit | (a[:, c:c + 1] & b[c:c + 1, :])
+        acc = acc | hit
+    acc_ref[...] = acc
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _finalize():
-        o_ref[...] = acc_ref[...]
+        o_ref[...] = acc_ref[...] != 0
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bw", "cw", "interpret"))
 def bitpack_matmul_pallas(ap: jax.Array, bp: jax.Array, *, bm: int = 128,
-                          bn: int = 128, bw: int = 8, cw: int = 8,
+                          bn: int = 128, bw: int = 128, cw: int = 8,
                           interpret: bool = False) -> jax.Array:
     """ap [M, W] uint32 (row-packed), bp [W, N] uint32 (col-packed) ->
-    or-and product [M, N] bool."""
+    or-and product [M, N] bool.  ``bw`` packed words per block sit on the
+    lane axis of ``ap``'s block, so the TPU needs ``bw`` = 128 or ``W``."""
     M, W = ap.shape
     W2, N = bp.shape
     assert W == W2 and M % bm == 0 and N % bn == 0 and W % bw == 0
@@ -90,7 +96,10 @@ def bitpack_matmul_pallas(ap: jax.Array, bp: jax.Array, *, bm: int = 128,
             pl.BlockSpec((bw, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.bool_),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.bool_)],
+        # inside shard_map the output varies over the mesh axes its
+        # operands vary over (shard_map checks this)
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.bool_,
+                                       vma=out_vma(ap, bp)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.uint32)],
         interpret=interpret,
     )(ap, bp)

@@ -19,7 +19,7 @@ def bitpack_bool_matmul(a: jax.Array, b: jax.Array,
     ap = pack_rows(a.astype(bool))                     # [M, W]
     bp = pack_cols(b.astype(bool))                     # [W, N]
     W = ap.shape[1]
-    bw = 8
+    bw = 128               # packed words per block: the TPU's lane width
     pm, pn, pw = (-M) % block, (-N) % block, (-W) % bw
     ap = jnp.pad(ap, ((0, pm), (0, pw)))
     bp = jnp.pad(bp, ((0, pw), (0, pn)))

@@ -10,7 +10,8 @@ thresholded (> 0) on the last K step.  Default blocks of 128 are
 MXU-aligned; three f32 128x128 buffers = 192 KiB, far under VMEM.
 
 Validated on CPU with interpret=True against ref.py (tests/test_kernels.py);
-compiled path is exercised by the dry-run on the TPU target.
+the compiled path is checked by tests/test_tpu_compile.py, which compiles
+the kernel for a described v5e chip at the engine's real widths.
 """
 from __future__ import annotations
 
@@ -20,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import out_vma
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -58,7 +61,10 @@ def bool_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.bool_),
+        # inside shard_map the output varies over the mesh axes its
+        # operands vary over (shard_map checks this)
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.bool_,
+                                       vma=out_vma(a, b)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a, b)

@@ -3,12 +3,14 @@
 C[i, j] = min_k ( A[i, k] + B[k, j] )      (int32, INF-saturating)
 
 The disDist closure hot spot (paper Sec. 4; DESIGN.md Sec. 2.1).  There is
-no MXU path for (min, +), so the kernel is VPU-shaped: for each (bm, bk) x
-(bk, bn) block pair it sweeps the contraction axis in chunks of ``ck``,
-materializing a [bm, ck, bn] broadcast-add in VMEM and folding it into the
-accumulator with a running elementwise min.  ck=8 keeps the intermediate at
-128*8*128*4B = 512 KiB worst-case; the accumulator persists across the K
-grid axis in VMEM scratch.
+no MXU path for (min, +), so the kernel is VPU-shaped and two-dimensional:
+each contraction step broadcasts one column of the A block along the lanes
+and one row of the B block along the sublanes, adds them into a
+[bm, bn] tile and folds it into the running minimum.  The bk steps are
+unrolled statically (the TPU lowering has no value-level dynamic slice);
+``ck`` consecutive steps fold into a partial minimum before it meets the
+accumulator, so the dependency chains stay short.  The [bm, bn] int32
+accumulator persists across the K grid axis in VMEM scratch.
 """
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import out_vma
 
 INF = 1 << 29    # python int: safe to close over inside the kernel body
 
@@ -29,16 +33,13 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int, ck: int):
 
     a = a_ref[...]                      # [bm, bk] int32
     b = b_ref[...]                      # [bk, bn] int32
-    bm, bk = a.shape
-    bn = b.shape[1]
-
-    def chunk(c, acc):
-        a_c = jax.lax.dynamic_slice(a, (0, c * ck), (bm, ck))
-        b_c = jax.lax.dynamic_slice(b, (c * ck, 0), (ck, bn))
-        vals = a_c[:, :, None] + b_c[None, :, :]      # [bm, ck, bn]
-        return jnp.minimum(acc, jnp.min(vals, axis=1))
-
-    acc = jax.lax.fori_loop(0, bk // ck, chunk, acc_ref[...])
+    bk = a.shape[1]
+    acc = acc_ref[...]
+    for c0 in range(0, bk, ck):
+        part = a[:, c0:c0 + 1] + b[c0:c0 + 1, :]      # [bm, bn]
+        for c in range(c0 + 1, c0 + ck):
+            part = jnp.minimum(part, a[:, c:c + 1] + b[c:c + 1, :])
+        acc = jnp.minimum(acc, part)
     acc_ref[...] = jnp.minimum(acc, INF)              # saturate
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -66,7 +67,10 @@ def tropical_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
+        # inside shard_map the output varies over the mesh axes its
+        # operands vary over (shard_map checks this)
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32,
+                                       vma=out_vma(a, b)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(a, b)

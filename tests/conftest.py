@@ -16,11 +16,11 @@ except ImportError:
     _hypothesis_stub.install(sys.modules)
 
 
-# jaxlib 0.4.x's CPU JIT sporadically segfaults in backend_compile once a
-# single process has accumulated enough live compiled executables (seen at
-# ~200 suite tests; reproducible at pristine checkouts, crash point moves
-# with compile count).  Dropping the caches between modules keeps the live
-# executable set small; each module only pays its own warm-up again.
+# Dropping JAX's caches between modules keeps the set of live compiled
+# executables in one process small (each module pays only its own warm-up
+# again).  It was added against a jaxlib CPU JIT that segfaulted in
+# backend_compile once a process held ~200 tests' executables; it also
+# bounds the memory a long single-process run holds.
 import pytest  # noqa: E402
 
 

@@ -310,7 +310,7 @@ prepare_rvset_cache(fr)
 mesh = fragment_mesh(k)
 rng = np.random.default_rng(0)
 
-ok, modes = True, []
+ok, modes, on_one_device = True, [], True
 for step in range(3):
     f = int(rng.integers(k))
     mine = np.nonzero(part == f)[0]
@@ -319,6 +319,9 @@ for step in range(3):
     adds += [(int(rng.choice(mine)), int(rng.choice(other)))]
     st = apply_delta_sharded(fr, GraphDelta.insert(adds), mesh=mesh)
     modes.append(st.mode)
+    on_one_device &= all(
+        len(getattr(fr.rvset_cache, name).sharding.device_set) == 1
+        for name in ("closure", "bl_frontier"))
     pairs = [(int(rng.integers(g.n)), int(rng.integers(g.n)))
              for _ in range(24)]
     got = dis_reach_batch(fr, pairs)
@@ -335,6 +338,7 @@ shape_ok = any(c.results and c.results[0].dtype == "ui32"
                and c.results[0].dims == (len(row_ids), words)
                for c in model.collectives)
 print(json.dumps({"ok": bool(ok), "modes": modes,
+                  "on_one_device": bool(on_one_device),
                   "n_collectives": len(model.collectives),
                   "payload_shape_ok": bool(shape_ok),
                   "rows": int(len(row_ids)), "nb": int(fr.n_boundary)}))
@@ -354,6 +358,10 @@ def sharded_update_report():
 def test_sharded_repair_correct(sharded_update_report):
     assert sharded_update_report["ok"]
     assert set(sharded_update_report["modes"]) == {"repair_sharded"}
+    # the repaired host cache stays on one device: spread over the mesh,
+    # its next closure update would need Pallas kernels partitioned, which
+    # the TPU refuses
+    assert sharded_update_report["on_one_device"], sharded_update_report
 
 
 def test_sharded_update_ships_changed_rows_only(sharded_update_report):

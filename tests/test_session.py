@@ -268,25 +268,27 @@ def test_group_traffic_sums_to_one_collective_vmap():
 # server: rpq kind, submit validation, batches spanning a delta
 # ---------------------------------------------------------------------------
 
-def test_sharded_device_inputs_memoized_until_delta():
+def test_sharded_device_inputs_memoized_until_delta(shard_map_report):
     """The batched sharded engines' device uploads (edge lists + boundary
     gathers) are built once per fragmentation state: repeat batches reuse
     the memo, and an apply_delta (which mutates the host arrays in place)
-    invalidates it via arrays_version."""
+    invalidates it via arrays_version.  The uploads are placed shard by
+    shard on the mesh they serve (a different placement missing the memo
+    is checked on 8 devices in the subprocess below)."""
     from repro.core import Placement, distributed
     g, fr = _case(16, 40, 2, 3)
-    pl = Placement.round_robin(fr.k, fr.k)
-    m1 = distributed._device_inputs(fr, pl)
-    assert distributed._device_inputs(fr, pl) is m1   # steady state: reused
-    # a different placement misses the (version, placement) memo key
-    other = Placement.balanced(fr, 1)
-    assert distributed._device_inputs(fr, other) is not m1
+    mesh = distributed.fragment_mesh(1)
+    pl = Placement.round_robin(fr.k, 1)
+    m1 = distributed._device_inputs(fr, pl, mesh)
+    assert distributed._device_inputs(fr, pl, mesh) is m1   # steady state
+    assert m1["arrs"]["esrc"].sharding.mesh == mesh
     v0 = fr.arrays_version
     fr.apply_delta(GraphDelta.insert([(0, 1)]))
     assert fr.arrays_version == v0 + 1
-    m2 = distributed._device_inputs(fr, pl)
+    m2 = distributed._device_inputs(fr, pl, mesh)
     assert m2 is not m1 and m2["version"] == fr.arrays_version
-    assert distributed._device_inputs(fr, pl) is m2
+    assert distributed._device_inputs(fr, pl, mesh) is m2
+    assert shard_map_report["memo_ok"], shard_map_report
 
 
 def test_server_submit_validates_kind_and_args():
@@ -417,6 +419,16 @@ for grp in sess.last_plan.groups:
 # crashing inside the engine
 mesh2 = fragment_mesh(2)
 mesh4 = fragment_mesh(4)
+from repro.core import Placement, distributed
+fr_m = fragment_graph(g, random_partition(g, 4, 0), 4)
+m1 = distributed._device_inputs(fr_m, Placement.round_robin(4, 2), mesh2)
+# a different placement misses the (version, placement, mesh) memo key,
+# and every packed upload is split across the mesh, not stacked on one device
+memo_ok = (distributed._device_inputs(fr_m, Placement.round_robin(4, 2),
+                                      mesh2) is m1
+           and distributed._device_inputs(fr_m, Placement.balanced(fr_m, 4),
+                                          mesh4) is not m1
+           and len(m1["arrs"]["esrc"].sharding.device_set) == 2)
 fr4 = fragment_graph(g, random_partition(g, 4, 0), 4)
 fr2 = fragment_graph(g, random_partition(g, 2, 0), 2)
 small = repro.connect(fr4, mesh=mesh2)        # 4 frags packed on 2 devices
@@ -482,7 +494,10 @@ print(json.dumps({"backend": sess.backend, "ok": got == want,
                   "mesh_ok": bool(mesh_ok),
                   "server_backend": srv.session.backend,
                   "update_mode": upd.value.mode,
-                  "server_ok": bool(server_ok)}))
+                  "server_ok": bool(server_ok),
+                  "memo_ok": bool(memo_ok),
+                  "degraded_groups": sum(x.stats.degraded_groups for x in (
+                      sess, small, sess2, srv.session))}))
 """
 
 
@@ -504,6 +519,9 @@ def test_session_shard_map_mixed_batch_subprocess(shard_map_report):
     assert rep["backend"] == "shard_map"
     assert rep["ok"], rep
     assert rep["executions"] == rep["groups"]
+    # exact answers must come from the sharded engines themselves, not
+    # from the vmap fallback that serves a failed sharded group
+    assert rep["degraded_groups"] == 0, rep
     # the random draw produced all three kinds -> all three sharded paths ran
     assert rep["kinds"] == ["dist", "reach", "rpq"], rep
 

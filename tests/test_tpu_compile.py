@@ -1,0 +1,82 @@
+"""The Pallas kernels compiled for a TPU v5e, without a chip.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a *described* ``v5e:2x2`` topology.  These tests compile each kernel
+itself with ``interpret=False`` at the engine's real widths -- the public
+wrappers would take their CPU branch here -- and require the Mosaic
+kernel (``tpu_custom_call``) in the compiled program.  That catches what
+interpret mode cannot: block shapes the TPU refuses and primitives it
+cannot lower.
+
+The topology is described in a fixture of this file only, never while a
+module is imported: only one process at a time may load the TPU library,
+and under pytest-xdist only the worker given this file does.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bitpack_ops.bitpack_ops import bitpack_matmul_pallas
+from repro.kernels.bool_matmul.bool_matmul import bool_matmul_pallas
+from repro.kernels.tropical_matmul.tropical_matmul import \
+    tropical_matmul_pallas
+
+SIDE = 8192        # boundary-matrix side: closure squaring is [SIDE, SIDE]^2
+ROWS = 128         # a padded batch: the combine is [ROWS, SIDE] x [SIDE, SIDE]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:    # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, shapes, dtype, sharding):
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=sharding) for s in shapes]
+    return jax.jit(lambda a, b: fn(a, b, interpret=False)).lower(
+        *args).compile().as_text()
+
+
+SEMIRING_KERNELS = {"or_and": (bool_matmul_pallas, jnp.bool_),
+                    "min_plus": (tropical_matmul_pallas, jnp.int32)}
+
+
+@pytest.mark.parametrize("kernel", sorted(SEMIRING_KERNELS))
+@pytest.mark.parametrize("use,shapes", [
+    ("closure", ((SIDE, SIDE), (SIDE, SIDE))),
+    ("combine", ((ROWS, SIDE), (SIDE, SIDE))),
+])
+def test_semiring_kernel_compiles_for_v5e(kernel, use, shapes, one_chip):
+    fn, dtype = SEMIRING_KERNELS[kernel]
+    assert "tpu_custom_call" in _compiled_text(fn, shapes, dtype, one_chip)
+
+
+def test_bitpack_kernel_compiles_for_v5e(one_chip):
+    """32 boundary nodes per uint32 word: a SIDE-wide Boolean closure
+    packs to SIDE // 32 words on the contraction axis."""
+    words = SIDE // 32
+    text = _compiled_text(bitpack_matmul_pallas,
+                          ((SIDE, words), (words, SIDE)), jnp.uint32,
+                          one_chip)
+    assert "tpu_custom_call" in text
