@@ -44,8 +44,7 @@ shipping in an artifact:
   3x the committed baseline (with a small-run absolute floor);
 * MVCC snapshot serving (``BENCH_pr9``): both runs must report
   ``answers_ok`` (every read verified against the graph snapshot named
-  by its stamped ``cache_version``) and carry the kernel roofline rows
-  (report-only — no perf gate on achieved-vs-peak yet); the committed
+  by its stamped ``cache_version``); the committed
   run's worst-mix barrier/mvcc read-p95 ratio must show MVCC retiring
   the write stall by >= 2x (the fast run gets a noise floor).
 
@@ -264,12 +263,6 @@ def main(argv=None) -> int:
             rep["answers_ok"],
             "every read exact against the per-snapshot replay oracle "
             "(stamped cache_version -> replayed graph), both modes",
-        )
-        check(
-            f"mvcc roofline coverage ({tag})",
-            len(rep["roofline"]["kernels"]) >= 3,
-            f"kernel roofline rows {sorted(rep['roofline']['kernels'])} "
-            "(report-only, no perf gate)",
         )
     ratio9_full = base9["read_p95_ratio_min"]
     check(

@@ -1,18 +1,7 @@
-"""Roofline analysis: dry-run records (deliverable (g)) + live kernels.
+"""Roofline analysis of dry-run records (deliverable (g)).
 
-Two entry points:
-
-* :func:`main` reads results/dryrun.json (written by repro.launch.dryrun)
-  and derives the three roofline terms per (arch x shape x mesh);
-* :func:`kernel_report` (PR-9) times the three semiring matmul kernels
-  that dominate the amortized-cache path **live** — no dryrun.json
-  needed — and reports achieved vs peak FLOP/s and bytes/s per kernel.
-  ``benchmarks.run`` folds the result into ``BENCH_pr9*.json``
-  (report-only: on the CPU CI runner the fractions of the TPU peaks are
-  tiny by construction; the point is the trajectory and the arithmetic-
-  intensity/ridge classification, which is hardware-independent).
-
-Dry-run terms per record:
+:func:`main` reads results/dryrun.json (written by repro.launch.dryrun)
+and derives the three roofline terms per (arch x shape x mesh):
 
   compute    = HLO_FLOPs_per_device / peak_FLOPs_per_chip
   memory     = HLO_bytes_per_device / HBM_bw
@@ -32,84 +21,6 @@ import sys
 PEAK_FLOPS = 197e12     # bf16 / chip
 HBM_BW = 819e9          # bytes/s
 ICI_BW = 50e9           # bytes/s per link (conservative single-link)
-RIDGE = PEAK_FLOPS / HBM_BW   # FLOP/byte where compute overtakes memory
-
-
-def _time_best(fn, repeats: int) -> float:
-    """Best-of-``repeats`` wall time of a blocked jax call (post-compile)."""
-    import time as _time
-
-    import jax
-    jax.block_until_ready(fn())          # compile + warm
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = _time.perf_counter()
-        jax.block_until_ready(fn())
-        best = min(best, _time.perf_counter() - t0)
-    return best
-
-
-def kernel_report(side: int = 256, batch: int = 64,
-                  repeats: int = 10, seed: int = 0) -> dict:
-    """Live achieved-vs-peak roofline for the semiring matmul kernels.
-
-    Times the three kernels the amortized-cache query path is built from
-    (``or_and_matmul``: the per-batch combine; ``min_plus_matmul``: its
-    tropical twin; ``bool_closure``: the repeated-squaring closure build)
-    on synthetic ``[batch, side] x [side, side]`` / ``[side, side]``
-    operands.  FLOPs/bytes are the analytic model of each kernel (two ops
-    per multiply-add; operands + result streamed once per matmul, the
-    closure doing ceil(log2 side) squarings), so the achieved numbers are
-    *model* FLOP/s — exactly the quantity the roofline bounds.
-    """
-    import math
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.core import bes
-    from repro.kernels.bool_matmul.ops import or_and_matmul
-    from repro.kernels.tropical_matmul.ops import min_plus_matmul
-
-    rng = np.random.default_rng(seed)
-    a_b = jnp.asarray(rng.random((batch, side)) < 0.05)
-    c_b = jnp.asarray(rng.random((side, side)) < 0.05)
-    a_t = jnp.asarray(rng.integers(0, 100, (batch, side)), jnp.int32)
-    c_t = jnp.asarray(rng.integers(0, 100, (side, side)), jnp.int32)
-    d0 = jnp.asarray(rng.random((side, side)) < (2.0 / side))
-    squarings = max(1, math.ceil(math.log2(side)))
-
-    kernels = {
-        "or_and_matmul": dict(
-            fn=lambda: or_and_matmul(a_b, c_b),
-            flops=2.0 * batch * side * side,
-            bytes=float(batch * side + side * side + batch * side)),
-        "min_plus_matmul": dict(
-            fn=lambda: min_plus_matmul(a_t, c_t),
-            flops=2.0 * batch * side * side,
-            bytes=4.0 * (batch * side + side * side + batch * side)),
-        "bool_closure": dict(
-            fn=lambda: bes.bool_closure(d0),
-            flops=squarings * 2.0 * side ** 3,
-            bytes=squarings * 3.0 * float(side * side)),
-    }
-    rows = {}
-    for name, spec in kernels.items():
-        t = _time_best(spec["fn"], repeats)
-        flops, nbytes = spec["flops"], spec["bytes"]
-        intensity = flops / nbytes
-        rows[name] = dict(
-            time_s=t,
-            model_flops=flops, model_bytes=nbytes,
-            achieved_flops_per_s=flops / t,
-            achieved_bytes_per_s=nbytes / t,
-            frac_peak_flops=flops / t / PEAK_FLOPS,
-            frac_peak_bw=nbytes / t / HBM_BW,
-            arithmetic_intensity=intensity,
-            bound="compute" if intensity > RIDGE else "memory")
-    return dict(side=side, batch=batch, repeats=repeats,
-                peak_flops=PEAK_FLOPS, hbm_bw=HBM_BW,
-                ridge_flops_per_byte=RIDGE, kernels=rows)
 
 
 def analyze(rec: dict) -> dict:

@@ -20,7 +20,6 @@ from repro.compile_cache import use_compile_cache
 from . import paper_experiments as pe
 from .exp_async_serve import exp_async_serve
 from .exp_mvcc import exp_mvcc
-from .roofline import kernel_report
 
 
 def _emit(section: str, rows):
@@ -222,22 +221,10 @@ def main() -> None:
               f"read_p95_ratio_min={res['read_p95_ratio_min']:.2f};"
               f"answers_ok={res['answers_ok']};"
               f"offered_qps={res['offered_qps']:.0f}")
-        # report-only roofline trajectory for the semiring kernels (no
-        # gate: CPU CI is far off the TPU peaks by construction)
-        roof = kernel_report(side=128 if fast else 256,
-                             batch=32 if fast else 64,
-                             repeats=5 if fast else 10)
-        for kname, r in roof["kernels"].items():
-            print(f"roofline/{kname},{r['time_s'] * 1e6:.1f},"
-                  f"frac_peak_flops={r['frac_peak_flops']:.2e};"
-                  f"frac_peak_bw={r['frac_peak_bw']:.2e};"
-                  f"intensity={r['arithmetic_intensity']:.2f};"
-                  f"bound={r['bound']}")
         out = "BENCH_pr9" + suffix
         with open(out, "w") as f:
             json.dump({"experiment": "mvcc_snapshot_serving",
-                       "fast_mode": fast, **res, "roofline": roof},
-                      f, indent=2)
+                       "fast_mode": fast, **res}, f, indent=2)
         print(f"# wrote {out}")
 
     section("# ISSUE-5: sharded one-collective batches, all query kinds",
@@ -249,7 +236,7 @@ def main() -> None:
     section("# ISSUE-8: continuous-batching async serving vs the sync "
             "drain pattern", async_serve)
     section("# ISSUE-9: MVCC non-blocking deltas vs the barrier write "
-            "path + kernel roofline", mvcc_bench)
+            "path", mvcc_bench)
 
     if failures:
         print(f"# FAILED sections ({len(failures)}): {failures}",

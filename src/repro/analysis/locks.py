@@ -1,12 +1,17 @@
 """Lock-order checker for the threaded serving + MVCC stack
 (DESIGN.md Sec. 10.3).
 
-The PR-8/PR-9 stack spans five locks; the declared partial order (outer
-first — a thread holding lock *i* may only acquire locks strictly later
-in the list) is:
+The PR-8/PR-9 stack spans five locks, and the span recorder of
+``repro.tracing`` a sixth; the declared partial order (outer first — a
+thread holding lock *i* may only acquire locks strictly later in the
+list) is:
 
     engine._serve_mutex  ->  engine._mutex  ->  store._repair_lock
         ->  session._lock  ->  store._lock  ->  telemetry._lock
+        ->  tracing._lock
+
+``tracing._lock`` is a leaf: spans close under any of the others, and
+nothing is acquired while it is held.
 
 ``engine._work`` and ``engine._repair_cond`` are Conditions built over
 ``engine._mutex`` and alias it.  ``session._lock`` and ``engine._mutex``
@@ -44,6 +49,7 @@ LOCK_ORDER = (
     "session._lock",
     "store._lock",
     "telemetry._lock",
+    "tracing._lock",
 )
 RANK = {name: i for i, name in enumerate(LOCK_ORDER)}
 REENTRANT = frozenset({"session._lock", "engine._mutex"})
@@ -54,6 +60,7 @@ DEFAULT_ROLES = {
     os.path.join("core", "session.py"): "session",
     os.path.join("core", "versions.py"): "store",
     os.path.join("serve", "telemetry.py"): "telemetry",
+    "tracing.py": "tracing",
 }
 # attribute names that resolve a cross-object call receiver to a role
 _RECEIVERS = {"session": "session", "store": "store", "_store": "store",

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from . import bes, engine
 from .automaton import QueryAutomaton
 from .engine import INF
@@ -145,7 +146,8 @@ def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
                                arrs)                        # [k, S, n+1]
         bl = _boundary_rows(fr, front, False, lambda ref, v: ref.max(v))
         D0 = _gather_boundary_matrix(fr, bl, fill=False)
-        C = bes.bool_closure(D0, use_pallas=use_pallas)
+        with tracing.span("repro.cache.closure", kind="reach"):
+            C = bes.bool_closure(D0, use_pallas=use_pallas)
         cache = RvsetCache(fr=fr, arrays=arrs, bl_frontier=bl, closure=C,
                            part_b=fr.boundary_owner())
         fr.rvset_cache = cache
@@ -156,7 +158,9 @@ def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
                               lambda ref, v: ref.min(v))
         W0 = _gather_boundary_matrix(fr, bl_d, fill=INF)
         cache.bl_dist = bl_d
-        cache.dist_closure = bes.tropical_closure(W0, use_pallas=use_pallas)
+        with tracing.span("repro.cache.closure", kind="dist"):
+            cache.dist_closure = bes.tropical_closure(W0,
+                                                      use_pallas=use_pallas)
     return cache
 
 
@@ -385,34 +389,45 @@ def _batch_reach_kernel(esrc, edst, tgt_local, bl, C, frag_s, s_slot,
                         t_slot_sfrag, t_cols, *, n_max: int):
     """N pairs -> N answers.  Shapes: esrc/edst [k, E]; tgt_local [k, B];
     bl [nb, n+1]; C [nb, nb]; frag_s/s_slot/t_slot_sfrag [N];
-    t_cols [N, nb] (slot of t_j inside the fragment owning boundary u)."""
+    t_cols [N, nb] (slot of t_j inside the fragment owning boundary u).
+
+    Its stages carry the named scopes ``local_stage``, ``gather`` and
+    ``combine``, which a profiler trace reads its device time by."""
     nb = C.shape[0]
-    es = jnp.take(esrc, frag_s, axis=0)                    # [N, E]
-    ed = jnp.take(edst, frag_s, axis=0)
-    f = jax.vmap(functools.partial(engine.single_source_reach,
-                                   n_max=n_max))(es, ed, s_slot)  # [N, n+1]
-    direct = jnp.take_along_axis(f, t_slot_sfrag[:, None], axis=1)[:, 0]
-    tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]    # [N, nb]
-    sb = jnp.take_along_axis(f, tgt_s, axis=1)             # [N, nb]
-    tc = jax.vmap(lambda c: bl[jnp.arange(nb), c])(t_cols)  # [N, nb]
-    return combine_bool(direct, sb, tc, C)
+    with jax.named_scope("gather"):
+        es = jnp.take(esrc, frag_s, axis=0)                # [N, E]
+        ed = jnp.take(edst, frag_s, axis=0)
+    with jax.named_scope("local_stage"):
+        f = jax.vmap(functools.partial(engine.single_source_reach,
+                                       n_max=n_max))(es, ed, s_slot)
+    with jax.named_scope("gather"):
+        direct = jnp.take_along_axis(f, t_slot_sfrag[:, None], axis=1)[:, 0]
+        tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]  # [N, nb]
+        sb = jnp.take_along_axis(f, tgt_s, axis=1)         # [N, nb]
+        tc = jax.vmap(lambda c: bl[jnp.arange(nb), c])(t_cols)  # [N, nb]
+    with jax.named_scope("combine"):
+        return combine_bool(direct, sb, tc, C)
 
 
 @functools.partial(jax.jit, static_argnames=("n_max",))
 def _batch_dist_kernel(esrc, edst, tgt_local, bl_d, Cd, frag_s, s_slot,
                        t_slot_sfrag, t_cols, *, n_max: int):
     """Tropical twin of :func:`_batch_reach_kernel`: N distances (INF if
-    unreachable)."""
+    unreachable), under the same named scopes."""
     nb = Cd.shape[0]
-    es = jnp.take(esrc, frag_s, axis=0)
-    ed = jnp.take(edst, frag_s, axis=0)
-    f = jax.vmap(functools.partial(engine.single_source_dist,
-                                   n_max=n_max))(es, ed, s_slot)  # [N, n+1]
-    direct = jnp.take_along_axis(f, t_slot_sfrag[:, None], axis=1)[:, 0]
-    tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]
-    sb = jnp.take_along_axis(f, tgt_s, axis=1)             # [N, nb]
-    tc = jax.vmap(lambda c: bl_d[jnp.arange(nb), c])(t_cols)
-    return combine_dist(direct, sb, tc, Cd)
+    with jax.named_scope("gather"):
+        es = jnp.take(esrc, frag_s, axis=0)
+        ed = jnp.take(edst, frag_s, axis=0)
+    with jax.named_scope("local_stage"):
+        f = jax.vmap(functools.partial(engine.single_source_dist,
+                                       n_max=n_max))(es, ed, s_slot)
+    with jax.named_scope("gather"):
+        direct = jnp.take_along_axis(f, t_slot_sfrag[:, None], axis=1)[:, 0]
+        tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]
+        sb = jnp.take_along_axis(f, tgt_s, axis=1)         # [N, nb]
+        tc = jax.vmap(lambda c: bl_d[jnp.arange(nb), c])(t_cols)
+    with jax.named_scope("combine"):
+        return combine_dist(direct, sb, tc, Cd)
 
 
 def _batch_inputs(fr: Fragmentation, cache: RvsetCache,
@@ -444,11 +459,12 @@ def dis_reach_batch(fr: Fragmentation, pairs) -> np.ndarray:
         return np.zeros(0, dtype=bool)
     cache = get_rvset_cache(fr)
     arrs = cache.arrays
-    out = _batch_reach_kernel(
-        arrs["esrc"], arrs["edst"], arrs["tgt_local"],
-        cache.bl_frontier, cache.closure,
-        *_batch_inputs(fr, cache, pairs), n_max=fr.n_max)
-    return np.asarray(out)
+    with tracing.span("repro.session.inputs"):
+        inputs = _batch_inputs(fr, cache, pairs)
+    with tracing.span("repro.session.device"):
+        return np.asarray(_batch_reach_kernel(
+            arrs["esrc"], arrs["edst"], arrs["tgt_local"],
+            cache.bl_frontier, cache.closure, *inputs, n_max=fr.n_max))
 
 
 def dis_dist_batch(fr: Fragmentation, pairs,
@@ -461,10 +477,13 @@ def dis_dist_batch(fr: Fragmentation, pairs,
         return np.zeros(0, dtype=bool if bound is not None else np.int64)
     cache = get_rvset_cache(fr, with_dist=True)
     arrs = cache.arrays
-    d = np.asarray(_batch_dist_kernel(
-        arrs["esrc"], arrs["edst"], arrs["tgt_local"],
-        cache.bl_dist, cache.dist_closure,
-        *_batch_inputs(fr, cache, pairs), n_max=fr.n_max)).astype(np.int64)
+    with tracing.span("repro.session.inputs"):
+        inputs = _batch_inputs(fr, cache, pairs)
+    with tracing.span("repro.session.device"):
+        d = np.asarray(_batch_dist_kernel(
+            arrs["esrc"], arrs["edst"], arrs["tgt_local"],
+            cache.bl_dist, cache.dist_closure, *inputs,
+            n_max=fr.n_max)).astype(np.int64)
     if bound is not None:
         return d <= bound
     d[d >= int(INF)] = -1
@@ -524,13 +543,14 @@ def product_closure(fr: Fragmentation, qa: QueryAutomaton,
             jnp.int32(NO_NODE), jnp.int32(NO_NODE), n_max=n_max, B=B)
         return D | rloc, None
 
-    D, _ = jax.lax.scan(fold, jnp.zeros((B * Q, B * Q), bool),
-                        tuple(arrs[name] for name in (
-                            "esrc", "edst", "src_local", "src_row",
-                            "tgt_local", "labels", "gids")))
-    nb = fr.n_boundary
-    D = D[:nb * Q, :nb * Q]          # the boundary rows/cols: a prefix
-    C = bes.bool_closure(D, use_pallas=use_pallas)
+    with tracing.span("repro.cache.closure", kind="rpq"):
+        D, _ = jax.lax.scan(fold, jnp.zeros((B * Q, B * Q), bool),
+                            tuple(arrs[name] for name in (
+                                "esrc", "edst", "src_local", "src_row",
+                                "tgt_local", "labels", "gids")))
+        nb = fr.n_boundary
+        D = D[:nb * Q, :nb * Q]          # the boundary rows/cols: a prefix
+        C = bes.bool_closure(D, use_pallas=use_pallas)
     # bound the per-automaton cache: each closure is (nb*Q)^2 bools, and a
     # server facing user-supplied regexes must not grow without limit.
     # dict order is recency order (hits re-insert at the MRU end), so the
@@ -557,29 +577,36 @@ def _batch_rpq_kernel(esrc, edst, labels, gids, tgt_local, q_labels, q_trans,
     """
     Q = q_labels.shape[0]
     nb = part_b.shape[0]
-    es = jnp.take(esrc, frag_s, axis=0)                    # [N, E]
-    ed = jnp.take(edst, frag_s, axis=0)
-    lab = jnp.take(labels, frag_s, axis=0)
-    gid = jnp.take(gids, frag_s, axis=0)
-    f = jax.vmap(lambda a, b, c, d, sl, sg, tg: engine.single_source_regular(
-        a, b, c, d, q_labels, q_trans, sl, q_start, sg, tg,
-        n_max=n_max))(es, ed, lab, gid, s_slot, s_gids, t_gids)  # [N,n+1,Q]
-    direct = jnp.take_along_axis(f[:, :, Q - 1], t_slot_sfrag[:, None],
-                                 axis=1)[:, 0]             # [N]
-    rev = jax.vmap(lambda ts, sg, tg: jax.vmap(
-        lambda a, b, c, d, tslot: engine.reverse_target_regular(
-            a, b, c, d, q_labels, q_trans, tslot, sg, tg,
-            n_max=n_max))(esrc, edst, labels, gids, ts))(
-        t_slots, s_gids, t_gids)                           # [N, k, n+1, Q]
-    if nb == 0:
-        return direct
-    tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]    # [N, nb]
-    sb = jnp.take_along_axis(f, tgt_s[:, :, None], axis=1)  # [N, nb, Q]
-    # spare boundary slots read the (all-false) pad row of rev via local_b
-    tc = rev[:, part_b, local_b, :]                        # [N, nb, Q]
+    with jax.named_scope("gather"):
+        es = jnp.take(esrc, frag_s, axis=0)                # [N, E]
+        ed = jnp.take(edst, frag_s, axis=0)
+        lab = jnp.take(labels, frag_s, axis=0)
+        gid = jnp.take(gids, frag_s, axis=0)
+    with jax.named_scope("local_stage"):
+        f = jax.vmap(lambda a, b, c, d, sl, sg, tg:
+                     engine.single_source_regular(
+                         a, b, c, d, q_labels, q_trans, sl, q_start, sg, tg,
+                         n_max=n_max))(es, ed, lab, gid, s_slot, s_gids,
+                                       t_gids)             # [N, n+1, Q]
+        rev = jax.vmap(lambda ts, sg, tg: jax.vmap(
+            lambda a, b, c, d, tslot: engine.reverse_target_regular(
+                a, b, c, d, q_labels, q_trans, tslot, sg, tg,
+                n_max=n_max))(esrc, edst, labels, gids, ts))(
+            t_slots, s_gids, t_gids)                       # [N, k, n+1, Q]
+    with jax.named_scope("gather"):
+        direct = jnp.take_along_axis(f[:, :, Q - 1], t_slot_sfrag[:, None],
+                                     axis=1)[:, 0]         # [N]
+        if nb == 0:
+            return direct
+        tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]  # [N, nb]
+        sb = jnp.take_along_axis(f, tgt_s[:, :, None], axis=1)  # [N, nb, Q]
+        # spare boundary slots read the (all-false) pad row of rev via
+        # local_b
+        tc = rev[:, part_b, local_b, :]                    # [N, nb, Q]
     N = f.shape[0]
-    return combine_bool(direct, sb.reshape(N, nb * Q),
-                        tc.reshape(N, nb * Q), C)
+    with jax.named_scope("combine"):
+        return combine_bool(direct, sb.reshape(N, nb * Q),
+                            tc.reshape(N, nb * Q), C)
 
 
 def dis_rpq_batch(fr: Fragmentation, pairs, qa: QueryAutomaton) -> np.ndarray:
@@ -597,18 +624,21 @@ def dis_rpq_batch(fr: Fragmentation, pairs, qa: QueryAutomaton) -> np.ndarray:
     cache = get_rvset_cache(fr)
     arrs = cache.arrays
     ss, tt = pairs[:, 0], pairs[:, 1]
-    slot_of = fr.slot_index()
-    frag_s = fr.part[ss].astype(np.int32)
-    out = _batch_rpq_kernel(
-        arrs["esrc"], arrs["edst"], arrs["labels"], arrs["gids"],
-        arrs["tgt_local"], jnp.asarray(qa.state_labels),
-        jnp.asarray(qa.trans), jnp.int32(qa.start), C,
-        jnp.asarray(cache.part_b), jnp.asarray(fr.boundary_local()),
-        jnp.asarray(frag_s), jnp.asarray(fr.owner_local[ss].astype(np.int32)),
-        jnp.asarray(slot_of[tt, frag_s]), jnp.asarray(slot_of[tt, :]),
-        jnp.asarray(ss.astype(np.int32)), jnp.asarray(tt.astype(np.int32)),
-        n_max=fr.n_max)
-    ans = np.array(out)                    # copy: jax buffers are read-only
+    with tracing.span("repro.session.inputs"):
+        slot_of = fr.slot_index()
+        frag_s = fr.part[ss].astype(np.int32)
+        inputs = (
+            jnp.asarray(qa.state_labels), jnp.asarray(qa.trans),
+            jnp.int32(qa.start), C, jnp.asarray(cache.part_b),
+            jnp.asarray(fr.boundary_local()), jnp.asarray(frag_s),
+            jnp.asarray(fr.owner_local[ss].astype(np.int32)),
+            jnp.asarray(slot_of[tt, frag_s]), jnp.asarray(slot_of[tt, :]),
+            jnp.asarray(ss.astype(np.int32)),
+            jnp.asarray(tt.astype(np.int32)))
+    with tracing.span("repro.session.device"):
+        ans = np.array(_batch_rpq_kernel(   # copy: jax buffers are read-only
+            arrs["esrc"], arrs["edst"], arrs["labels"], arrs["gids"],
+            arrs["tgt_local"], *inputs, n_max=fr.n_max))
     ans[ss == tt] = bool(qa.nullable)      # convention: s==t is |R|-free
     return ans
 

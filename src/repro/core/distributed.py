@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import tracing
 from . import cache as _cache
 from . import engine
 from ..kernels.bitpack_ops.ops import pack_payload, unpack_payload
@@ -85,7 +86,8 @@ def dis_reach_sharded(fr: Fragmentation, s: int, t: int,
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=(P(), P()))
-    def run(esrc, edst, src_local, src_row, tgt_local, s_local, t_local):
+    def sharded_reach_one(esrc, edst, src_local, src_row, tgt_local,
+                          s_local, t_local):
         rloc = engine.local_eval_reach(
             esrc[0], edst[0], src_local[0], src_row[0], tgt_local[0],
             s_local[0], t_local[0], n_max=fr.n_max, B=fr.B)
@@ -96,7 +98,7 @@ def dis_reach_sharded(fr: Fragmentation, s: int, t: int,
         ans = engine.evaldg_reach(D, src_rows, tgt_cols)
         return ans, D
 
-    ans, D = jax.jit(run)(*(args[k] for k in
+    ans, D = jax.jit(sharded_reach_one)(*(args[k] for k in
                             ("esrc", "edst", "src_local", "src_row",
                              "tgt_local", "s_local", "t_local")))
     return bool(ans), np.asarray(D)
@@ -139,8 +141,8 @@ def dis_rpq_sharded(fr: Fragmentation, s: int, t: int, qa: QueryAutomaton,
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
-    def run(esrc, edst, src_local, src_row, tgt_local, labels, gids,
-            s_local, t_local):
+    def sharded_rpq_one(esrc, edst, src_local, src_row, tgt_local, labels,
+                        gids, s_local, t_local):
         rloc = engine.local_eval_regular(
             esrc[0], edst[0], src_local[0], src_row[0], tgt_local[0],
             labels[0], gids[0], q_labels, q_trans,
@@ -150,7 +152,7 @@ def dis_rpq_sharded(fr: Fragmentation, s: int, t: int, qa: QueryAutomaton,
         D = unpack_payload(Dp, fr.B * Q)
         return engine.evaldg_reach(D, src_rows, tgt_cols)
 
-    ans = jax.jit(run)(*(args[k] for k in names))
+    ans = jax.jit(sharded_rpq_one)(*(args[k] for k in names))
     return bool(ans)
 
 
@@ -168,7 +170,8 @@ def lower_reach_hlo(fr: Fragmentation, s: int, t: int,
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
-    def run(esrc, edst, src_local, src_row, tgt_local, s_local, t_local):
+    def sharded_reach_one(esrc, edst, src_local, src_row, tgt_local,
+                          s_local, t_local):
         rloc = engine.local_eval_reach(
             esrc[0], edst[0], src_local[0], src_row[0], tgt_local[0],
             s_local[0], t_local[0], n_max=fr.n_max, B=fr.B)
@@ -176,7 +179,7 @@ def lower_reach_hlo(fr: Fragmentation, s: int, t: int,
         D = unpack_payload(Dp, fr.B)
         return engine.evaldg_reach(D, src_rows, tgt_cols)
 
-    lowered = jax.jit(run).lower(*(args[k] for k in names))
+    lowered = jax.jit(sharded_reach_one).lower(*(args[k] for k in names))
     return lowered.as_text()
 
 
@@ -248,25 +251,30 @@ def _batch_reach_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int, N: int):
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
-    def run(esrc, edst, src_local, tgt_local, s_slot, t_slot, srcidx, own):
+    def sharded_reach(esrc, edst, src_local, tgt_local, s_slot, t_slot,
+                      srcidx, own):
         # each arg arrives [fpd, ...]: this device's owned fragments
-        d0, sb, direct, tc = _cache.local_stage_reach_packed(
-            esrc, edst, src_local, s_slot, t_slot,
-            srcidx, own, tgt_local[:, :nb], n_max=n_max,
-            axis_name=FRAG_AXIS)
-        payload = jnp.concatenate([
-            jnp.concatenate([d0, jnp.zeros((nb, 1), bool)], axis=1),
-            jnp.concatenate([sb, direct[:, None]], axis=1),
-            jnp.concatenate([tc, jnp.zeros((N, 1), bool)], axis=1),
-        ], axis=0)                                         # [nb+2N, nb+1]
-        merged = unpack_payload(
-            jax.lax.psum(pack_payload(payload), FRAG_AXIS), nb + 1)
-        d0_m, sb_m, direct_m, tc_m = _split_merged(merged, nb, N)
+        with jax.named_scope("local_stage"):
+            d0, sb, direct, tc = _cache.local_stage_reach_packed(
+                esrc, edst, src_local, s_slot, t_slot,
+                srcidx, own, tgt_local[:, :nb], n_max=n_max,
+                axis_name=FRAG_AXIS)
+        with jax.named_scope("collective"):
+            payload = jnp.concatenate([
+                jnp.concatenate([d0, jnp.zeros((nb, 1), bool)], axis=1),
+                jnp.concatenate([sb, direct[:, None]], axis=1),
+                jnp.concatenate([tc, jnp.zeros((N, 1), bool)], axis=1),
+            ], axis=0)                                     # [nb+2N, nb+1]
+            merged = unpack_payload(
+                jax.lax.psum(pack_payload(payload), FRAG_AXIS), nb + 1)
+            d0_m, sb_m, direct_m, tc_m = _split_merged(merged, nb, N)
         # replicated: closure by repeated squaring + per-pair combine
-        return _cache.combine_bool(direct_m, sb_m, tc_m,
-                                         bool_closure(d0_m))
+        with jax.named_scope("closure"):
+            C = bool_closure(d0_m)
+        with jax.named_scope("combine"):
+            return _cache.combine_bool(direct_m, sb_m, tc_m, C)
 
-    return jax.jit(run)
+    return jax.jit(sharded_reach)
 
 
 @functools.lru_cache(maxsize=64)
@@ -275,29 +283,35 @@ def _batch_dist_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int, N: int):
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
-    def run(esrc, edst, src_local, tgt_local, s_slot, t_slot, srcidx, own):
-        w0, sb, direct, tc = _cache.local_stage_dist_packed(
-            esrc, edst, src_local, s_slot, t_slot,
-            srcidx, own, tgt_local[:, :nb], n_max=n_max,
-            axis_name=FRAG_AXIS)
-        inf_b = jnp.full((nb, 1), engine.INF, jnp.int32)
-        inf_n = jnp.full((N, 1), engine.INF, jnp.int32)
-        payload = jnp.concatenate([
-            jnp.concatenate([w0, inf_b], axis=1),
-            jnp.concatenate([sb, direct[:, None]], axis=1),
-            jnp.concatenate([tc, inf_n], axis=1),
-        ], axis=0)                                         # [nb+2N, nb+1]
-        # the ONE collective: min-reduce the int32 tropical wire — exact
-        # because every entry is computed on exactly one device (w0 and sb
-        # rows by their owner, tc[:, u] by frag(u)) and all others ship
-        # INF, the tropical zero.  int32 rows do not bitpack, so the wire
-        # carries the rows actually contributed, never the B^2 matrix.
-        merged = jax.lax.pmin(payload, FRAG_AXIS)
-        w0_m, sb_m, direct_m, tc_m = _split_merged(merged, nb, N)
-        return _cache.combine_dist(direct_m, sb_m, tc_m,
-                                         tropical_closure(w0_m))
+    def sharded_dist(esrc, edst, src_local, tgt_local, s_slot, t_slot,
+                     srcidx, own):
+        with jax.named_scope("local_stage"):
+            w0, sb, direct, tc = _cache.local_stage_dist_packed(
+                esrc, edst, src_local, s_slot, t_slot,
+                srcidx, own, tgt_local[:, :nb], n_max=n_max,
+                axis_name=FRAG_AXIS)
+        with jax.named_scope("collective"):
+            inf_b = jnp.full((nb, 1), engine.INF, jnp.int32)
+            inf_n = jnp.full((N, 1), engine.INF, jnp.int32)
+            payload = jnp.concatenate([
+                jnp.concatenate([w0, inf_b], axis=1),
+                jnp.concatenate([sb, direct[:, None]], axis=1),
+                jnp.concatenate([tc, inf_n], axis=1),
+            ], axis=0)                                     # [nb+2N, nb+1]
+            # the ONE collective: min-reduce the int32 tropical wire —
+            # exact because every entry is computed on exactly one device
+            # (w0 and sb rows by their owner, tc[:, u] by frag(u)) and all
+            # others ship INF, the tropical zero.  int32 rows do not
+            # bitpack, so the wire carries the rows actually contributed,
+            # never the B^2 matrix.
+            merged = jax.lax.pmin(payload, FRAG_AXIS)
+            w0_m, sb_m, direct_m, tc_m = _split_merged(merged, nb, N)
+        with jax.named_scope("closure"):
+            Cd = tropical_closure(w0_m)
+        with jax.named_scope("combine"):
+            return _cache.combine_dist(direct_m, sb_m, tc_m, Cd)
 
-    return jax.jit(run)
+    return jax.jit(sharded_dist)
 
 
 @functools.lru_cache(maxsize=64)
@@ -309,26 +323,30 @@ def _batch_rpq_jitted(mesh: Mesh, nb: int, n_max: int, B: int, Q: int,
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=P())
-    def run(esrc, edst, src_local, src_row, tgt_local, labels, gids,
-            s_slot, t_slot, mine, q_labels, q_trans, s_gids, t_gids,
-            local_b):
-        d0, sb, direct, tc = _cache.local_stage_rpq_packed(
-            esrc, edst, src_local, src_row, tgt_local,
-            labels, gids, q_labels, q_trans, jnp.int32(q_start),
-            s_slot, t_slot, s_gids, t_gids, local_b, mine,
-            n_max=n_max, B=B, axis_name=FRAG_AXIS)
-        payload = jnp.concatenate([
-            jnp.concatenate([d0, jnp.zeros((side, 1), bool)], axis=1),
-            jnp.concatenate([sb, direct[:, None]], axis=1),
-            jnp.concatenate([tc, jnp.zeros((N, 1), bool)], axis=1),
-        ], axis=0)                                 # [side+2N, side+1]
-        merged = unpack_payload(
-            jax.lax.psum(pack_payload(payload), FRAG_AXIS), side + 1)
-        d0_m, sb_m, direct_m, tc_m = _split_merged(merged, side, N)
-        return _cache.combine_bool(direct_m, sb_m, tc_m,
-                                         bool_closure(d0_m))
+    def sharded_rpq(esrc, edst, src_local, src_row, tgt_local, labels, gids,
+                    s_slot, t_slot, mine, q_labels, q_trans, s_gids, t_gids,
+                    local_b):
+        with jax.named_scope("local_stage"):
+            d0, sb, direct, tc = _cache.local_stage_rpq_packed(
+                esrc, edst, src_local, src_row, tgt_local,
+                labels, gids, q_labels, q_trans, jnp.int32(q_start),
+                s_slot, t_slot, s_gids, t_gids, local_b, mine,
+                n_max=n_max, B=B, axis_name=FRAG_AXIS)
+        with jax.named_scope("collective"):
+            payload = jnp.concatenate([
+                jnp.concatenate([d0, jnp.zeros((side, 1), bool)], axis=1),
+                jnp.concatenate([sb, direct[:, None]], axis=1),
+                jnp.concatenate([tc, jnp.zeros((N, 1), bool)], axis=1),
+            ], axis=0)                             # [side+2N, side+1]
+            merged = unpack_payload(
+                jax.lax.psum(pack_payload(payload), FRAG_AXIS), side + 1)
+            d0_m, sb_m, direct_m, tc_m = _split_merged(merged, side, N)
+        with jax.named_scope("closure"):
+            C = bool_closure(d0_m)
+        with jax.named_scope("combine"):
+            return _cache.combine_bool(direct_m, sb_m, tc_m, C)
 
-    return jax.jit(run)
+    return jax.jit(sharded_rpq)
 
 
 
@@ -482,11 +500,13 @@ def dis_reach_batch_sharded(fr: Fragmentation, pairs,
     pairs = _as_batch_pairs(pairs)
     if len(pairs) == 0:
         return np.zeros(0, dtype=bool)
-    run, args = _batch_sharded_program(fr, pairs, "reach", mesh=mesh,
-                                       placement=placement, chaos=chaos)
+    with tracing.span("repro.session.inputs"):
+        run, args = _batch_sharded_program(fr, pairs, "reach", mesh=mesh,
+                                           placement=placement, chaos=chaos)
     if chaos is not None:
         chaos.maybe_fail("engine.shard_map", pairs=pairs)
-    ans = np.array(run(*args))
+    with tracing.span("repro.session.device"):
+        ans = np.array(run(*args))
     ans[pairs[:, 0] == pairs[:, 1]] = True
     return ans
 
@@ -503,11 +523,13 @@ def dis_dist_batch_sharded(fr: Fragmentation, pairs,
     pairs = _as_batch_pairs(pairs)
     if len(pairs) == 0:
         return np.zeros(0, dtype=np.int64)
-    run, args = _batch_sharded_program(fr, pairs, "dist", mesh=mesh,
-                                       placement=placement, chaos=chaos)
+    with tracing.span("repro.session.inputs"):
+        run, args = _batch_sharded_program(fr, pairs, "dist", mesh=mesh,
+                                           placement=placement, chaos=chaos)
     if chaos is not None:
         chaos.maybe_fail("engine.shard_map", pairs=pairs)
-    d = np.asarray(run(*args)).astype(np.int64)
+    with tracing.span("repro.session.device"):
+        d = np.asarray(run(*args)).astype(np.int64)
     d[d >= int(engine.INF)] = -1
     return d
 
@@ -525,11 +547,14 @@ def dis_rpq_batch_sharded(fr: Fragmentation, pairs, qa: QueryAutomaton,
     pairs = _as_batch_pairs(pairs)
     if len(pairs) == 0:
         return np.zeros(0, dtype=bool)
-    run, args = _batch_sharded_program(fr, pairs, "rpq", qa=qa, mesh=mesh,
-                                       placement=placement, chaos=chaos)
+    with tracing.span("repro.session.inputs"):
+        run, args = _batch_sharded_program(fr, pairs, "rpq", qa=qa,
+                                           mesh=mesh, placement=placement,
+                                           chaos=chaos)
     if chaos is not None:
         chaos.maybe_fail("engine.shard_map", pairs=pairs)
-    ans = np.array(run(*args))
+    with tracing.span("repro.session.device"):
+        ans = np.array(run(*args))
     ans[pairs[:, 0] == pairs[:, 1]] = bool(qa.nullable)
     return ans
 
@@ -581,27 +606,28 @@ def _update_rows_jitted(mesh: Mesh, nb: int, n_max: int, fpd: int):
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=(P(), P(FRAG_AXIS)))
-    def run(esrc, edst, init, srcidx, own, tgt_local):
+    def sharded_delta(esrc, edst, init, srcidx, own, tgt_local):
         # [fpd, ...] per device: resume every owned fragment's fixpoint
         # (fragments untouched by the delta — including ones co-packed
         # with a dirty neighbour — start at fixpoint and converge in one
         # relaxation; inert pads converge in zero)
-        F = jax.vmap(functools.partial(
-            engine.resume_frontier_reach, n_max=n_max))(
-            esrc, edst, init)                              # [fpd, S, n+1]
-
         def one(Ff, sidx, ownf, tloc):
             rows = jnp.take(Ff, sidx, axis=0)              # [r, n+1]
             return jnp.take(rows, tloc[:nb], axis=1) & ownf[:, None]
 
-        d0r = jnp.any(jax.vmap(one)(F, srcidx, own, tgt_local), axis=0)
+        with jax.named_scope("local_stage"):
+            F = jax.vmap(functools.partial(
+                engine.resume_frontier_reach, n_max=n_max))(
+                esrc, edst, init)                          # [fpd, S, n+1]
+            d0r = jnp.any(jax.vmap(one)(F, srcidx, own, tgt_local), axis=0)
         # the ONE update collective: changed rows only, bitpacked (pmax ==
         # OR: each row is owned by exactly one device, others ship zeros)
-        merged = unpack_payload(jax.lax.pmax(pack_payload(d0r), FRAG_AXIS),
-                                nb)
+        with jax.named_scope("collective"):
+            merged = unpack_payload(
+                jax.lax.pmax(pack_payload(d0r), FRAG_AXIS), nb)
         return merged, F
 
-    return jax.jit(run)
+    return jax.jit(sharded_delta)
 
 
 def _update_rows_program(fr: Fragmentation, warm_init: np.ndarray,

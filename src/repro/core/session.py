@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from . import cache as _cache
 from . import engine, incremental
 from ..errors import DeltaApplyFailed, Status
@@ -52,6 +53,10 @@ class SessionStats:
     # robustness accounting (DESIGN.md Sec. 7)
     degraded_groups: int = 0  # sharded groups served by the vmap fallback
     rollbacks: int = 0        # failed deltas rolled back to their snapshot
+    # rows of the cached groups' programs: the queries, and the bucket rows
+    # the planner padded them to (the kernels' own padding is not counted)
+    rows_useful: int = 0
+    rows_padded: int = 0
 
 
 def connect(fr: Fragmentation, backend: str = "auto",
@@ -249,15 +254,18 @@ class QuerySession:
             queries = [queries]
         queries = list(queries)
         fr = self.fr if version is None else version.fr
-        with self._lock:
-            plan = plan_queries(queries, self._resolve_automaton)
+        with tracing.span("repro.session.run", n=len(queries)), self._lock:
+            with tracing.span("repro.session.plan"):
+                plan = plan_queries(queries, self._resolve_automaton)
             self.last_plan = plan
             results: List[Optional[QueryResult]] = [None] * len(queries)
             for group in plan.groups:
-                if self.cache_mode == "amortized":
-                    self._run_group_cached(fr, group, results)
-                else:
-                    self._run_group_uncached(fr, group, results)
+                with tracing.span("repro.session.group", kind=group.kind,
+                                  n=group.n, bucket=group.padded_size):
+                    if self.cache_mode == "amortized":
+                        self._run_group_cached(fr, group, results)
+                    else:
+                        self._run_group_uncached(fr, group, results)
             # uncached execution never consults the cache: stamp None even
             # if a cache happens to exist on the shared fragmentation
             if self.cache_mode != "amortized":
@@ -265,9 +273,9 @@ class QuerySession:
             else:
                 c = fr.rvset_cache
                 stamp = None if c is None else c.version
-        for r in results:
-            r.cache_version = stamp
-            r.status = Status.DONE
+            for r in results:
+                r.cache_version = stamp
+                r.status = Status.DONE
         self.stats.queries += len(queries)
         self.stats.batches += 1
         return results  # type: ignore[return-value]
@@ -311,21 +319,28 @@ class QuerySession:
         stats = self._group_stats(fr, group)
         ans, degraded = self._execute_group(fr, group.kind, pairs,
                                             group.automaton)
-        if group.kind == "reach":
-            for i, q, a, st in zip(group.indices, group.queries, ans, stats):
-                results[i] = self._reach_result(q, a, st)
-        elif group.kind == "dist":
-            # exact distances once; each query's bound applies at answer
-            # extraction (this is what lets bounded + exact queries fuse)
-            for i, q, di, st in zip(group.indices, group.queries, ans, stats):
-                results[i] = self._dist_result(q, int(di), st)
-        else:                                   # rpq
-            for i, q, a, st in zip(group.indices, group.queries, ans, stats):
-                results[i] = self._rpq_result(q, group.automaton, a, st)
-        if degraded:
-            for i in group.indices:
-                results[i].degraded = True
+        with tracing.span("repro.session.assemble"):
+            if group.kind == "reach":
+                for i, q, a, st in zip(group.indices, group.queries, ans,
+                                       stats):
+                    results[i] = self._reach_result(q, a, st)
+            elif group.kind == "dist":
+                # exact distances once; each query's bound applies at
+                # answer extraction (this is what lets bounded + exact
+                # queries fuse)
+                for i, q, di, st in zip(group.indices, group.queries, ans,
+                                        stats):
+                    results[i] = self._dist_result(q, int(di), st)
+            else:                                   # rpq
+                for i, q, a, st in zip(group.indices, group.queries, ans,
+                                       stats):
+                    results[i] = self._rpq_result(q, group.automaton, a, st)
+            if degraded:
+                for i in group.indices:
+                    results[i].degraded = True
         self.stats.executions += 1
+        self.stats.rows_useful += group.n
+        self.stats.rows_padded += group.padded_size
 
     def _execute_group(self, fr: Fragmentation, kind: str, pairs, qa):
         """One batched engine execution; returns ``(answers, degraded)``.

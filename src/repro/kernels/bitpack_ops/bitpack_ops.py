@@ -102,4 +102,5 @@ def bitpack_matmul_pallas(ap: jax.Array, bp: jax.Array, *, bm: int = 128,
                                        vma=out_vma(ap, bp)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.uint32)],
         interpret=interpret,
+        name="bitpack_matmul",
     )(ap, bp)

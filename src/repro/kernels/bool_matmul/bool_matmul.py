@@ -67,4 +67,5 @@ def bool_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
                                        vma=out_vma(a, b)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="bool_matmul",
     )(a, b)

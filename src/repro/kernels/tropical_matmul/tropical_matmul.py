@@ -73,4 +73,5 @@ def tropical_matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128,
                                        vma=out_vma(a, b)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="tropical_matmul",
     )(a, b)

@@ -54,6 +54,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from .. import tracing
 from ..core.automaton import QueryAutomaton
 from ..core.fragments import GraphDelta
 from ..core.plan import Dist, Query, Reach, Rpq
@@ -436,7 +437,9 @@ class AsyncQueryEngine:
             else:
                 self._apply_update(work)
         else:
-            self._serve_chunk(work)
+            # the formed chunk to its last resolved future
+            with tracing.span("repro.serve.batch", n=len(work)):
+                self._serve_chunk(work)
 
     def _repair_loop(self) -> None:
         """MVCC repair worker: commit pending deltas as new versions,
@@ -613,8 +616,9 @@ class AsyncQueryEngine:
                 if getattr(exc, "permanent", False):
                     break                      # retrying cannot help
                 continue
-            for r in reqs:
-                self._resolve(r, Status.DONE)
+            with tracing.span("repro.serve.resolve"):
+                for r in reqs:
+                    self._resolve(r, Status.DONE)
             return
         if len(reqs) == 1:
             r = reqs[0]

@@ -261,4 +261,4 @@ def test_monitored_serving_stack_end_to_end():
 def test_lock_order_is_total_and_matches_design():
     assert list(LOCK_ORDER) == [
         "engine._serve_mutex", "engine._mutex", "store._repair_lock",
-        "session._lock", "store._lock", "telemetry._lock"]
+        "session._lock", "store._lock", "telemetry._lock", "tracing._lock"]

@@ -13,6 +13,7 @@ module is imported: only one process at a time may load the TPU library,
 and under pytest-xdist only the worker given this file does.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,3 +81,19 @@ def test_bitpack_kernel_compiles_for_v5e(one_chip):
                           ((SIDE, words), (words, SIDE)), jnp.uint32,
                           one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("fn,dtype,shapes,name", [
+    (bool_matmul_pallas, jnp.bool_, ((256, 256), (256, 256)),
+     "bool_matmul"),
+    (tropical_matmul_pallas, jnp.int32, ((256, 256), (256, 256)),
+     "tropical_matmul"),
+    (bitpack_matmul_pallas, jnp.uint32, ((256, 128), (128, 256)),
+     "bitpack_matmul"),
+])
+def test_kernel_named_in_compiled_program(fn, dtype, shapes, name,
+                                          one_chip):
+    """Each Pallas call carries its kernel's name, which names its op in a
+    device trace."""
+    text = _compiled_text(fn, shapes, dtype, one_chip)
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text), name
