@@ -20,6 +20,10 @@ serving engine.  This module is the *language* half of that engine:
 Distances with and without a bound share a group: the cached tropical
 kernel computes exact distances and the bound is applied per-query at
 answer extraction, so ``Dist(s, t)`` and ``Dist(s, t, bound=l)`` fuse.
+Reachability is the same case with the bound at infinity
+(``reach(s, t)`` iff ``dist(s, t) < INF`` on these unweighted graphs), so
+where the session asks for it (``reach_in_dist``) a batch's reach reads
+join its dist group too.
 
 Execution lives in :mod:`repro.core.session`; this module stays importable
 without touching a device.
@@ -185,6 +189,12 @@ class ExecutionGroup:
     def padded_size(self) -> int:
         return bucket_size(self.n)
 
+    @property
+    def n_reach(self) -> int:
+        """Reach reads in the group: all of a reach group's, and those a
+        dist group answers from its distances."""
+        return sum(isinstance(q, Reach) for q in self.queries)
+
     def pairs(self) -> np.ndarray:
         """[padded_size, 2] int64 (s, t) rows; padding repeats row 0, whose
         answer is computed once more and discarded (semiring no-op)."""
@@ -220,7 +230,7 @@ class QueryPlan:
 
 def plan_queries(queries: Sequence[Query],
                  resolve_automaton: Callable[[Rpq], QueryAutomaton],
-                 ) -> QueryPlan:
+                 reach_in_dist: bool = False) -> QueryPlan:
     """Group a heterogeneous batch by (kind, automaton) execution signature.
 
     ``resolve_automaton`` turns an :class:`Rpq` into its
@@ -228,11 +238,25 @@ def plan_queries(queries: Sequence[Query],
     graph labels); two RPQs land in the same group iff their automata have
     equal :meth:`QueryAutomaton.cache_key`, which is also the key the
     product-closure cache uses — one group == one closure == one program.
+
+    ``reach_in_dist``: when the batch holds a :class:`Dist` read, its
+    :class:`Reach` reads join the ``("dist",)`` group, whose distances
+    answer them (``dist >= 0``), unless the joined group would pad to
+    more rows than the two groups it replaces.  The session sets it only
+    where the dist program is already paid for (DESIGN.md Sec. 5,
+    "Planner").
     """
+    reach_key: Tuple = ("reach",)
+    if reach_in_dist:
+        n_reach = sum(isinstance(q, Reach) for q in queries)
+        n_dist = sum(isinstance(q, Dist) for q in queries)
+        if n_dist and bucket_size(n_reach + n_dist) <= (
+                bucket_size(n_reach) + bucket_size(n_dist)):
+            reach_key = ("dist",)
     groups: dict = {}
     for i, q in enumerate(queries):
         if isinstance(q, Reach):
-            key: Tuple = ("reach",)
+            key: Tuple = reach_key
             qa = None
         elif isinstance(q, Dist):
             key = ("dist",)

@@ -57,6 +57,10 @@ class SessionStats:
     # the planner padded them to (the kernels' own padding is not counted)
     rows_useful: int = 0
     rows_padded: int = 0
+    # reach reads the cached groups answered, and of those the ones a dist
+    # group answered from its distances (the planner's reach_in_dist)
+    reach_rows: int = 0
+    reach_fused: int = 0
 
 
 def connect(fr: Fragmentation, backend: str = "auto",
@@ -256,12 +260,14 @@ class QuerySession:
         fr = self.fr if version is None else version.fr
         with tracing.span("repro.session.run", n=len(queries)), self._lock:
             with tracing.span("repro.session.plan"):
-                plan = plan_queries(queries, self._resolve_automaton)
+                plan = plan_queries(queries, self._resolve_automaton,
+                                    reach_in_dist=self._reach_in_dist(fr))
             self.last_plan = plan
             results: List[Optional[QueryResult]] = [None] * len(queries)
             for group in plan.groups:
                 with tracing.span("repro.session.group", kind=group.kind,
-                                  n=group.n, bucket=group.padded_size):
+                                  n=group.n, bucket=group.padded_size,
+                                  reach=group.n_reach):
                     if self.cache_mode == "amortized":
                         self._run_group_cached(fr, group, results)
                     else:
@@ -295,6 +301,16 @@ class QuerySession:
 
     # -- internals ---------------------------------------------------------
 
+    def _reach_in_dist(self, fr: Fragmentation) -> bool:
+        """Whether a batch's reach reads join its dist group: on the cached
+        vmap path once ``fr``'s tropical closure is built, so the dist
+        program runs anyway and a reach read never triggers that build.
+        On shard_map each batch closes its own tropical matrix and reach
+        keeps the cheaper Boolean program (DESIGN.md Sec. 5)."""
+        c = fr.rvset_cache
+        return (self.cache_mode == "amortized" and self.backend == "vmap"
+                and c is not None and c.bl_dist is not None)
+
     def _resolve_automaton(self, q: Rpq) -> QueryAutomaton:
         if q.automaton is not None:
             return q.automaton
@@ -327,10 +343,12 @@ class QuerySession:
             elif group.kind == "dist":
                 # exact distances once; each query's bound applies at
                 # answer extraction (this is what lets bounded + exact
-                # queries fuse)
+                # queries fuse), and a reach read is one with no bound
                 for i, q, di, st in zip(group.indices, group.queries, ans,
                                         stats):
-                    results[i] = self._dist_result(q, int(di), st)
+                    results[i] = (self._reach_result(q, di >= 0, st)
+                                  if isinstance(q, Reach)
+                                  else self._dist_result(q, int(di), st))
             else:                                   # rpq
                 for i, q, a, st in zip(group.indices, group.queries, ans,
                                        stats):
@@ -341,6 +359,9 @@ class QuerySession:
         self.stats.executions += 1
         self.stats.rows_useful += group.n
         self.stats.rows_padded += group.padded_size
+        self.stats.reach_rows += group.n_reach
+        if group.kind == "dist":
+            self.stats.reach_fused += group.n_reach
 
     def _execute_group(self, fr: Fragmentation, kind: str, pairs, qa):
         """One batched engine execution; returns ``(answers, degraded)``.

@@ -145,6 +145,111 @@ def test_planner_groups_by_kind_and_automaton():
     assert "fused executions" in plan.explain()
 
 
+@pytest.mark.parametrize("reach_in_dist", [False, True])
+def test_planner_reach_in_dist_joins_the_dist_group(reach_in_dist):
+    qa = _automaton(REGEXES[0])
+    queries = [Reach(0, 1), Dist(0, 1), Rpq(0, 1, automaton=qa),
+               Reach(2, 3), Dist(2, 3, bound=2), Reach(4, 4)]
+    plan = plan_queries(queries, lambda q: q.automaton,
+                        reach_in_dist=reach_in_dist)
+    got = [(grp.kind, grp.indices, grp.n_reach) for grp in plan.groups]
+    if reach_in_dist:
+        # one tropical group, in submission order, reach reads included
+        assert got == [("dist", [0, 1, 3, 4, 5], 3), ("rpq", [2], 0)]
+    else:
+        assert got == [("reach", [0, 3, 5], 3), ("dist", [1, 4], 0),
+                       ("rpq", [2], 0)]
+    # a joined group padded past the two it replaces stays apart: 30
+    # reach and 10 dist reads run 32 + 16 rows, not 64
+    wide = [Reach(i, i + 1) for i in range(30)] + [Dist(i, 1)
+                                                    for i in range(10)]
+    plan = plan_queries(wide, lambda q: q.automaton,
+                        reach_in_dist=reach_in_dist)
+    assert [(grp.kind, grp.padded_size) for grp in plan.groups] == [
+        ("reach", 32), ("dist", 16)]
+    # a batch without a Dist read keeps its reach group either way
+    plan = plan_queries([Reach(0, 1), Rpq(0, 1, automaton=qa)],
+                        lambda q: q.automaton, reach_in_dist=reach_in_dist)
+    assert [(grp.kind, grp.n) for grp in plan.groups] == [("reach", 1),
+                                                           ("rpq", 1)]
+
+
+def _reach_dist_batch(n, rng):
+    """Mixed reach / exact / bounded reads with s == t cases."""
+    pairs = [(int(rng.integers(n)), int(rng.integers(n))) for _ in range(14)]
+    pairs += [(3, 3), (5, 5)]
+    return [[Reach(s, t), Dist(s, t), Dist(s, t, bound=i % 4)][i % 3]
+            for i, (s, t) in enumerate(pairs)]
+
+
+def test_reach_answered_by_the_dist_group_is_exact():
+    """On a vmap session whose tropical closure is built, a mixed batch's
+    reach reads ride its dist group and answer exactly like an uncached
+    session and the oracles; the counters say so, and the group's stats
+    still sum to its one collective."""
+    g, fr = _case(40, 90, 4, 11)
+    sess = repro.connect(fr, backend="vmap").warm(with_dist=True)
+    queries = _reach_dist_batch(g.n, np.random.default_rng(5))
+    n_reach = sum(isinstance(q, Reach) for q in queries)
+    before = dict(vars(sess.stats))
+    res = sess.run(queries)
+    assert [(grp.kind, grp.n) for grp in sess.last_plan.groups] == [
+        ("dist", len(queries))]
+    assert sess.stats.reach_fused - before["reach_fused"] == n_reach
+    assert sess.stats.reach_rows - before["reach_rows"] == n_reach
+    want = repro.connect(fr, backend="vmap", cache="none").run(queries)
+    for q, r, w in zip(queries, res, want):
+        assert (r.answer, r.distance) == (w.answer, w.distance), q
+        if isinstance(q, Reach):
+            assert r.answer == oracle_reach(g, q.s, q.t), q
+            assert r.distance == (0 if q.s == q.t else None), q
+        elif q.bound is None:
+            assert r.distance == oracle_dist(g, q.s, q.t), q
+        else:
+            d = oracle_dist(g, q.s, q.t)
+            assert r.answer == (d is not None and d <= q.bound), q
+    grp = sess.last_plan.groups[0]
+    assert sum(r.stats.payload_bits for r in res) == fr.traffic_bits(
+        "dist", batch=grp.padded_size)
+    assert sum(r.stats.collective_rounds for r in res) == 1
+
+
+@pytest.mark.parametrize("case", ["reach_only", "unwarmed",
+                                  "warmed_without_dist", "cache_none",
+                                  "shard_map"])
+def test_reach_keeps_its_group_where_the_rule_does_not_apply(case):
+    g, fr = _case(30, 70, 3, 12)
+    queries = _reach_dist_batch(g.n, np.random.default_rng(6))
+    if case == "reach_only":
+        queries = [q for q in queries if isinstance(q, Reach)]
+    backend = "shard_map" if case == "shard_map" else "vmap"
+    sess = repro.connect(fr, backend=backend,
+                         cache="none" if case == "cache_none" else "amortized")
+    if case in ("reach_only", "cache_none", "shard_map"):
+        # the fragmentation holds the tropical closure: only the batch,
+        # the cache mode or the backend keeps the rule off
+        repro.connect(fr, backend="vmap").warm(with_dist=True)
+        assert fr.rvset_cache.bl_dist is not None
+    elif case == "warmed_without_dist":
+        sess.warm()
+    res = sess.run(queries)
+    assert sess.stats.reach_fused == 0
+    want = plan_queries(queries, sess._resolve_automaton)
+    assert [(grp.kind, grp.indices) for grp in sess.last_plan.groups] == \
+        [(grp.kind, grp.indices) for grp in want.groups]
+    assert "reach" in [grp.kind for grp in sess.last_plan.groups]
+    for q, r in zip(queries, res):
+        if isinstance(q, Reach):
+            assert r.answer == oracle_reach(g, q.s, q.t), q
+    if case in ("unwarmed", "warmed_without_dist"):
+        # that batch's dist group built the tropical closure, so from the
+        # next batch on the reach reads join it
+        sess.run(queries)
+        assert [grp.kind for grp in sess.last_plan.groups] == ["dist"]
+        assert sess.stats.reach_fused == sum(isinstance(q, Reach)
+                                             for q in queries)
+
+
 def test_bucket_padding_avoids_retraces():
     assert [bucket_size(n) for n in (1, 8, 9, 16, 17, 100)] == \
         [8, 8, 16, 16, 32, 128]
