@@ -27,7 +27,8 @@ aliasing).  The tropical and product-automaton variants replace (OR, AND)
 with (min, +) and the state-expanded matrix respectively.
 
 Batched: ``dis_reach_batch(fr, pairs)`` answers N pairs in ONE jitted call —
-N vmapped single-source propagations + one [N, |V_f|] x [|V_f|, |V_f|]
+N single-source propagations, relaxed as the lanes of one fixpoint over
+their fragments' shared edge list, + one [N, |V_f|] x [|V_f|, |V_f|]
 or-and matmul against the cached closure.
 """
 from __future__ import annotations
@@ -384,55 +385,135 @@ def local_stage_rpq_packed(esrc, edst, src_local, src_row, tgt_local, labels,
 # batched per-query phase (one jitted call for N pairs)
 # ---------------------------------------------------------------------------
 
+def fragment_blocks(n_rows: int, k: int) -> int:
+    """Fragment blocks the local stage of an ``n_rows`` batch relaxes over
+    ``k`` fragments: every fragment once the batch has as many rows, else
+    one per row (its distinct source fragments, padded by repeats)."""
+    return min(n_rows, k)
+
+
+def _union_edges(esrc, edst, blocks, n_max: int):
+    """The edge lists of the fragments ``blocks`` [m] (``None``: all k, in
+    order) as one list over ``m*(n_max+1)`` slots, block p's shifted by
+    ``p*(n_max+1)`` and the whole sorted by destination: ``(es, ed)``
+    [m*E].  Pad edges keep their self-loop, on their block's pad slot."""
+    if blocks is not None:
+        esrc = jnp.take(esrc, blocks, axis=0)
+        edst = jnp.take(edst, blocks, axis=0)
+    off = jnp.arange(esrc.shape[0], dtype=esrc.dtype)[:, None] * (n_max + 1)
+    ed, es = jax.lax.sort(((edst + off).reshape(-1),
+                           (esrc + off).reshape(-1)),
+                          num_keys=1, is_stable=False)
+    return es, ed
+
+
+def _relax_lanes(es, ed, F, tropical: bool):
+    """Fixpoint of the lanes ``F [m*(n_max+1), N]`` (one column per row)
+    over the sorted union edge list: each round one row gather by ``es``
+    and one sorted row reduction by ``ed``.  The rounds, the +1 and the
+    INF cap are ``engine._propagate_dist``'s (``_propagate_bool``'s when
+    not ``tropical``), so each column reaches the same fixpoint in the
+    same rounds as its single-source propagation."""
+    n_slots = F.shape[0]
+
+    def step(state):
+        cur, _ = state
+        msgs = jnp.take(cur, es, axis=0)                       # [m*E, N]
+        if tropical:
+            agg = jax.ops.segment_min(msgs + 1, ed, num_segments=n_slots,
+                                      indices_are_sorted=True)
+            new = jnp.minimum(cur, agg)
+            new = jnp.where(new > INF, INF, new)
+        else:
+            agg = jax.ops.segment_max(msgs.astype(jnp.int8), ed,
+                                      num_segments=n_slots,
+                                      indices_are_sorted=True)
+            new = cur | (agg > 0)
+        return new, jnp.any(new != cur)
+
+    init = jnp.any(F < INF) if tropical else jnp.any(F)
+    return jax.lax.while_loop(lambda st: st[1], step, (F, init))[0]
+
+
+def _lane_frontiers(esrc, edst, blocks, pos, s_slot, *, n_max: int,
+                    tropical: bool):
+    """Single-source frontiers of N rows as lanes over the union of m
+    fragment blocks: ``F [m*(n_max+1), N]``, row i in column i and in the
+    slots of its block, ``F[pos[i]*(n_max+1) + v, i]`` being what
+    ``engine.single_source_*`` gives for slot v of its fragment.
+
+    ``blocks`` [m] names the fragments (``None``: all k, and then ``pos``
+    is the source fragment itself; see :func:`fragment_blocks`), ``pos``
+    [N] row i's block, ``s_slot`` [N] its source slot (``n_max``: none).
+    Blocks share no edge, so the other blocks of a column stay at the
+    semiring zero (DESIGN.md Sec. 3.1)."""
+    N = s_slot.shape[0]
+    zero, one, dtype = ((INF, 0, jnp.int32) if tropical
+                        else (False, True, jnp.bool_))
+    with jax.named_scope("gather"):
+        with jax.named_scope("union_edges"):
+            es, ed = _union_edges(esrc, edst, blocks, n_max)
+        m = esrc.shape[0] if blocks is None else blocks.shape[0]
+        F = jnp.full((m * (n_max + 1), N), zero, dtype)
+        F = F.at[pos * (n_max + 1) + s_slot, jnp.arange(N)].set(
+            jnp.where(s_slot < n_max, one, zero))
+    with jax.named_scope("local_stage"):
+        return _relax_lanes(es, ed, F, tropical)
+
+
+def _batch_local(esrc, edst, tgt_local, bl, blocks, pos, s_slot,
+                 t_slot_sfrag, t_cols, *, n_max: int, tropical: bool):
+    """Local stage and gathers of a batch, shared by both semirings:
+    ``(direct [N], sb [N, nb], tc [N, nb])``."""
+    N, nb = s_slot.shape[0], bl.shape[0]
+    F = _lane_frontiers(esrc, edst, blocks, pos, s_slot, n_max=n_max,
+                        tropical=tropical)
+    with jax.named_scope("gather"):
+        base, lane = pos * (n_max + 1), jnp.arange(N)
+        frag_s = pos if blocks is None else jnp.take(blocks, pos)
+        direct = F[base + t_slot_sfrag, lane]                  # [N]
+        tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]    # [N, nb]
+        sb = F[base[:, None] + tgt_s, lane[:, None]]           # [N, nb]
+        tc = jax.vmap(lambda c: bl[jnp.arange(nb), c])(t_cols)  # [N, nb]
+    return direct, sb, tc
+
+
 @functools.partial(jax.jit, static_argnames=("n_max",))
-def _batch_reach_kernel(esrc, edst, tgt_local, bl, C, frag_s, s_slot,
+def _batch_reach_kernel(esrc, edst, tgt_local, bl, C, blocks, pos, s_slot,
                         t_slot_sfrag, t_cols, *, n_max: int):
     """N pairs -> N answers.  Shapes: esrc/edst [k, E]; tgt_local [k, B];
-    bl [nb, n+1]; C [nb, nb]; frag_s/s_slot/t_slot_sfrag [N];
-    t_cols [N, nb] (slot of t_j inside the fragment owning boundary u).
+    bl [nb, n+1]; C [nb, nb]; blocks [m] or None; pos/s_slot/t_slot_sfrag
+    [N]; t_cols [N, nb] (slot of t_j inside the fragment owning boundary
+    u).  ``_batch_inputs`` makes the per-batch ones.
 
     Its stages carry the named scopes ``local_stage``, ``gather`` and
     ``combine``, which a profiler trace reads its device time by."""
-    nb = C.shape[0]
-    with jax.named_scope("gather"):
-        es = jnp.take(esrc, frag_s, axis=0)                # [N, E]
-        ed = jnp.take(edst, frag_s, axis=0)
-    with jax.named_scope("local_stage"):
-        f = jax.vmap(functools.partial(engine.single_source_reach,
-                                       n_max=n_max))(es, ed, s_slot)
-    with jax.named_scope("gather"):
-        direct = jnp.take_along_axis(f, t_slot_sfrag[:, None], axis=1)[:, 0]
-        tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]  # [N, nb]
-        sb = jnp.take_along_axis(f, tgt_s, axis=1)         # [N, nb]
-        tc = jax.vmap(lambda c: bl[jnp.arange(nb), c])(t_cols)  # [N, nb]
+    direct, sb, tc = _batch_local(esrc, edst, tgt_local, bl, blocks, pos,
+                                  s_slot, t_slot_sfrag, t_cols, n_max=n_max,
+                                  tropical=False)
     with jax.named_scope("combine"):
         return combine_bool(direct, sb, tc, C)
 
 
 @functools.partial(jax.jit, static_argnames=("n_max",))
-def _batch_dist_kernel(esrc, edst, tgt_local, bl_d, Cd, frag_s, s_slot,
+def _batch_dist_kernel(esrc, edst, tgt_local, bl_d, Cd, blocks, pos, s_slot,
                        t_slot_sfrag, t_cols, *, n_max: int):
     """Tropical twin of :func:`_batch_reach_kernel`: N distances (INF if
     unreachable), under the same named scopes."""
-    nb = Cd.shape[0]
-    with jax.named_scope("gather"):
-        es = jnp.take(esrc, frag_s, axis=0)
-        ed = jnp.take(edst, frag_s, axis=0)
-    with jax.named_scope("local_stage"):
-        f = jax.vmap(functools.partial(engine.single_source_dist,
-                                       n_max=n_max))(es, ed, s_slot)
-    with jax.named_scope("gather"):
-        direct = jnp.take_along_axis(f, t_slot_sfrag[:, None], axis=1)[:, 0]
-        tgt_s = jnp.take(tgt_local, frag_s, axis=0)[:, :nb]
-        sb = jnp.take_along_axis(f, tgt_s, axis=1)         # [N, nb]
-        tc = jax.vmap(lambda c: bl_d[jnp.arange(nb), c])(t_cols)
+    direct, sb, tc = _batch_local(esrc, edst, tgt_local, bl_d, blocks, pos,
+                                  s_slot, t_slot_sfrag, t_cols, n_max=n_max,
+                                  tropical=True)
     with jax.named_scope("combine"):
         return combine_dist(direct, sb, tc, Cd)
 
 
 def _batch_inputs(fr: Fragmentation, cache: RvsetCache,
                   pairs: np.ndarray):
-    """Host-side per-batch index arrays (pure numpy gathers)."""
+    """Host-side per-batch index arrays (pure numpy gathers), in the
+    kernels' order from ``blocks`` on.  A batch of fewer rows than
+    fragments relaxes only its distinct source fragments, padded by
+    repeats to one block per row; a larger one relaxes all of them and
+    needs no block list (:func:`fragment_blocks`)."""
     ss, tt = pairs[:, 0], pairs[:, 1]
     slot_of = fr.slot_index()                              # [n, k]
     frag_s = fr.part[ss].astype(np.int32)
@@ -440,7 +521,12 @@ def _batch_inputs(fr: Fragmentation, cache: RvsetCache,
     t_slot_sfrag = slot_of[tt, frag_s]                     # [N]
     # slot of t_j inside the fragment owning each boundary node u
     t_cols = slot_of[tt][:, cache.part_b]                  # [N, nb]
-    return (jnp.asarray(frag_s), jnp.asarray(s_slot),
+    if fragment_blocks(len(pairs), fr.k) == fr.k:
+        blocks, pos = None, frag_s
+    else:
+        uniq, pos = np.unique(frag_s, return_inverse=True)
+        blocks = jnp.asarray(np.resize(uniq, len(pairs)).astype(np.int32))
+    return (blocks, jnp.asarray(pos.astype(np.int32)), jnp.asarray(s_slot),
             jnp.asarray(t_slot_sfrag), jnp.asarray(t_cols))
 
 

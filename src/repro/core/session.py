@@ -61,6 +61,10 @@ class SessionStats:
     # group answered from its distances (the planner's reach_in_dist)
     reach_rows: int = 0
     reach_fused: int = 0
+    # fragment blocks the cached vmap reach/dist groups relaxed their rows
+    # over (cache.fragment_blocks): rows_useful / frag_blocks rows share
+    # one edge list
+    frag_blocks: int = 0
 
 
 def connect(fr: Fragmentation, backend: str = "auto",
@@ -267,7 +271,8 @@ class QuerySession:
             for group in plan.groups:
                 with tracing.span("repro.session.group", kind=group.kind,
                                   n=group.n, bucket=group.padded_size,
-                                  reach=group.n_reach):
+                                  reach=group.n_reach,
+                                  frags=self._frag_blocks(fr, group)):
                     if self.cache_mode == "amortized":
                         self._run_group_cached(fr, group, results)
                     else:
@@ -310,6 +315,15 @@ class QuerySession:
         c = fr.rvset_cache
         return (self.cache_mode == "amortized" and self.backend == "vmap"
                 and c is not None and c.bl_dist is not None)
+
+    def _frag_blocks(self, fr: Fragmentation, group: ExecutionGroup) -> int:
+        """Fragment blocks the group's local stage relaxes its rows over
+        (``cache.fragment_blocks``); 0 where no lane stage runs: uncached,
+        RPQ and shard_map groups."""
+        if (self.cache_mode != "amortized" or self.backend != "vmap"
+                or group.kind == "rpq"):
+            return 0
+        return _cache.fragment_blocks(group.padded_size, fr.k)
 
     def _resolve_automaton(self, q: Rpq) -> QueryAutomaton:
         if q.automaton is not None:
@@ -359,6 +373,7 @@ class QuerySession:
         self.stats.executions += 1
         self.stats.rows_useful += group.n
         self.stats.rows_padded += group.padded_size
+        self.stats.frag_blocks += self._frag_blocks(fr, group)
         self.stats.reach_rows += group.n_reach
         if group.kind == "dist":
             self.stats.reach_fused += group.n_reach

@@ -11,6 +11,7 @@ point.)
 """
 import zlib
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -19,6 +20,8 @@ from repro import connect
 from repro.core import (Dist, Reach, build_query_automaton, dis_dist,
                         dis_reach, dis_rpq, fragment_graph, get_rvset_cache,
                         prepare_rvset_cache)
+from repro.core import cache as _cache
+from repro.core import engine
 from repro.graph import erdos_renyi, random_partition
 from repro.serve import QueryServer
 
@@ -212,3 +215,106 @@ def test_query_server_matches_oracle_across_batches():
             assert r.value == want
         else:
             assert r.value == (want is not None and want <= 2)
+
+
+# ---------------------------------------------------------------------------
+# the batched local stage: rows as lanes over the blocks' union edge list
+# ---------------------------------------------------------------------------
+
+LANE_K = 12        # more fragments than the smallest bucket, fewer than 16
+
+
+@pytest.fixture(scope="module")
+def lane_versions():
+    """A 12-fragment graph warmed with both semirings, and the MVCC head
+    after one delta that inserts edges and deletes others (a deletion
+    swaps the fragment's last edge slot into the freed one, so the edge
+    lists change order, not only content)."""
+    from repro.core import GraphDelta
+    from repro.core.versions import VersionedCacheStore
+    g = erdos_renyi(160, 420, n_labels=3, seed=7)
+    fr = fragment_graph(g, random_partition(g, LANE_K, 7), LANE_K,
+                        reserve_boundary=16, reserve_edges=16,
+                        reserve_stubs=16)
+    sess = connect(fr, backend="vmap").warm(with_dist=True)
+    store = VersionedCacheStore(sess, capacity=4)
+    rng = np.random.default_rng(7)
+    gone = rng.choice(len(g.src), size=12, replace=False)
+    new = rng.integers(0, g.n, size=(12, 2))
+    delta = GraphDelta(add_src=new[:, 0], add_dst=new[:, 1],
+                       del_src=g.src[gone], del_dst=g.dst[gone])
+    ver, _ = store.commit_delta(delta)
+    assert ver.fr is not fr and ver.fr.rvset_cache.bl_dist is not None
+    return {"base": fr, "written": ver.fr}
+
+
+def _lane_rows(fr, bucket, rng):
+    """``bucket`` query pairs, the first three sources in one fragment and
+    the fourth a boundary in-node, and their kernel inputs, with the
+    second row's source moved onto a virtual-node stub slot of its
+    fragment and the last row a pad row (source slot ``n_max``)."""
+    same = np.flatnonzero(fr.part == fr.part[0])[:3]
+    srcs = np.concatenate([same, fr.bnodes[:1],
+                           rng.integers(0, fr.g.n, bucket)])[:bucket]
+    pairs = np.stack([srcs, rng.integers(0, fr.g.n, bucket)], 1)
+    cache = get_rvset_cache(fr, with_dist=True)
+    blocks, pos, s_slot, _, _ = _cache._batch_inputs(fr, cache, pairs)
+    s_slot = np.array(s_slot)
+    stubs = sorted(fr.stubs[int(fr.part[srcs[1]])].values())
+    assert stubs, "the second source's fragment has no stub"
+    s_slot[1] = stubs[0]
+    s_slot[-1] = fr.n_max
+    return pairs, blocks, pos, s_slot
+
+
+@pytest.mark.parametrize("version", ["base", "written"])
+@pytest.mark.parametrize("tropical", [False, True], ids=["reach", "dist"])
+@pytest.mark.parametrize("bucket", [8, 16, 32, 64])
+def test_lane_frontiers_equal_single_source(lane_versions, bucket, tropical,
+                                            version):
+    """Every row of the lane relaxation holds exactly its single-source
+    fixpoint, with fewer rows than fragments (the batch's own blocks) and
+    with more (all fragments); the batch answers equal the oracles, also
+    after a delta that reordered the edge slots."""
+    fr = lane_versions[version]
+    rng = np.random.default_rng(bucket)
+    pairs, blocks, pos, s_slot = _lane_rows(fr, bucket, rng)
+    m = _cache.fragment_blocks(bucket, fr.k)
+    assert (blocks is None) == (bucket >= fr.k)
+    assert m == (fr.k if blocks is None else len(blocks))
+    pos = np.asarray(pos)
+    frag = pos if blocks is None else np.asarray(blocks)[pos]
+    assert np.array_equal(frag, fr.part[pairs[:, 0]])
+    arrs = get_rvset_cache(fr).arrays
+    F = jax.jit(_cache._lane_frontiers,
+                static_argnames=("n_max", "tropical"))(
+        arrs["esrc"], arrs["edst"], blocks, pos, s_slot, n_max=fr.n_max,
+        tropical=tropical)
+    lanes = np.asarray(F).reshape(m, fr.n_max + 1, bucket)
+    got = lanes[pos, :, np.arange(bucket)]                   # [N, n+1]
+    single = engine.single_source_dist if tropical \
+        else engine.single_source_reach
+    want = jax.vmap(lambda es, ed, sl: single(es, ed, sl, n_max=fr.n_max))(
+        arrs["esrc"][frag], arrs["edst"][frag], s_slot)
+    assert np.array_equal(got, np.asarray(want))
+    assert (got[-1] == (engine.INF if tropical else False)).all()  # pad row
+    # the union is every block's edges, shifted to its slots, in
+    # destination order (what the sorted row reduction relies on)
+    es, ed = _cache._union_edges(arrs["esrc"], arrs["edst"], blocks,
+                                 fr.n_max)
+    ed = np.asarray(ed)
+    assert (np.diff(ed) >= 0).all()
+    off = (np.arange(m) * (fr.n_max + 1))[:, None]
+    ids = np.arange(fr.k) if blocks is None else np.asarray(blocks)
+    want_edges = sorted(zip((arrs["esrc"][ids] + off).ravel().tolist(),
+                            (arrs["edst"][ids] + off).ravel().tolist()))
+    assert sorted(zip(np.asarray(es).tolist(), ed.tolist())) == want_edges
+    # the batch path itself, against the oracles on this version's graph
+    g = fr.g
+    if tropical:
+        d = _cache.dis_dist_batch(fr, pairs)
+        assert [None if x < 0 else int(x) for x in d] == [
+            oracle_dist(g, int(s), int(t)) for s, t in pairs]
+    else:
+        assert list(_cache.dis_reach_batch(fr, pairs)) == [
+            oracle_reach(g, int(s), int(t)) for s, t in pairs]

@@ -250,6 +250,42 @@ def test_reach_keeps_its_group_where_the_rule_does_not_apply(case):
                                              for q in queries)
 
 
+@pytest.mark.parametrize("case,n_queries,want", [
+    ("reach", 5, 8), ("dist", 9, 16), ("dist", 20, 16), ("rpq", 5, 0),
+    ("cache_none", 5, 0), ("shard_map", 5, 0)])
+def test_frag_blocks_count_the_lane_stage_blocks(case, n_queries, want):
+    """A cached vmap reach or dist group relaxes its bucket's rows over
+    min(bucket, k) fragment blocks (k = 16 here): the session counts them
+    in ``frag_blocks`` and the group span says ``frags``; groups without
+    that stage count 0."""
+    from repro import tracing
+    g, fr = _case(64, 160, 16, 4)
+    rng = np.random.default_rng(n_queries)
+    ends = rng.integers(0, g.n, size=(n_queries, 2))
+    make = {"reach": Reach, "cache_none": Reach, "shard_map": Reach,
+            "dist": Dist,
+            "rpq": lambda s, t: Rpq(s, t, regex="0*")}[case]
+    queries = [make(int(s), int(t)) for s, t in ends]
+    sess = repro.connect(
+        fr, backend="shard_map" if case == "shard_map" else "vmap",
+        cache="none" if case == "cache_none" else "amortized")
+    tracing.enable()
+    try:
+        res = sess.run(queries)
+        spans, _ = tracing.drain()
+    finally:
+        tracing.disable()
+    assert sess.stats.frag_blocks == want
+    [grp] = [s for s in spans if s.name == "repro.session.group"]
+    assert grp.attrs["frags"] == sess.stats.frag_blocks
+    if case == "reach":
+        for q, r in zip(queries, res):
+            assert r.answer == oracle_reach(g, q.s, q.t), q
+    elif case == "dist":
+        for q, r in zip(queries, res):
+            assert r.distance == oracle_dist(g, q.s, q.t), q
+
+
 def test_bucket_padding_avoids_retraces():
     assert [bucket_size(n) for n in (1, 8, 9, 16, 17, 100)] == \
         [8, 8, 16, 16, 32, 128]

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.cache import _batch_dist_kernel
 from repro.kernels.bitpack_ops.bitpack_ops import bitpack_matmul_pallas
 from repro.kernels.bool_matmul.bool_matmul import bool_matmul_pallas
 from repro.kernels.tropical_matmul.tropical_matmul import \
@@ -97,3 +98,44 @@ def test_kernel_named_in_compiled_program(fn, dtype, shapes, name,
     device trace."""
     text = _compiled_text(fn, shapes, dtype, one_chip)
     assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text), name
+
+
+# the benchmark graph's widths (bench/configs/er16x16k.json): fragments,
+# edge slots and local slots per fragment, boundary nodes; a full batch
+K, E_MAX, N_MAX, NB = 16, 66048, 16768, 3904
+BATCH = 64
+
+_GATHER = re.compile(r"= \w+\[([\d,]*)\]\S* gather\(.*slice_sizes=\{([\d,]+)\}")
+
+
+def _gathers(text):
+    """``(result shape, slice sizes)`` of every gather in a compiled
+    program."""
+    out = []
+    for line in text.splitlines():
+        m = _GATHER.search(line)
+        if m:
+            out.append((tuple(int(d) for d in m[1].split(",") if d),
+                        tuple(int(d) for d in m[2].split(","))))
+    return out
+
+
+def test_batch_local_stage_gathers_rows_not_scalars(one_chip):
+    """The batched dist program at the benchmark's widths relaxes its rows
+    as lanes: each round gathers whole N-wide rows of the frontier over
+    the union edge list, and no gather fetches one scalar per edge slot
+    (per row, as the per-row layout did: 4.2M scalar accesses a round).
+    The gathers of the answers' boundary entries, [N, nb] scalars, stay."""
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    text = _batch_dist_kernel.lower(
+        arg((K, E_MAX)), arg((K, E_MAX)), arg((K, NB + 2)),
+        arg((NB, N_MAX + 1)), arg((NB, NB)), None, arg((BATCH,)),
+        arg((BATCH,)), arg((BATCH,)), arg((BATCH, NB)),
+        n_max=N_MAX).compile().as_text()
+    gathers = _gathers(text)
+    scalar = [shape for shape, sizes in gathers
+              if set(sizes) == {1} and max(shape, default=0) >= E_MAX]
+    assert not scalar, scalar
+    assert ((K * E_MAX, BATCH), (1, BATCH)) in gathers, gathers

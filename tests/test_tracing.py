@@ -87,6 +87,8 @@ def test_spans_nest_and_share_a_batch_id_through_the_server(fr, recording):
     assert sum(g.attrs["reach"] for g in groups) == 2
     for g in groups:
         assert g.attrs["bucket"] >= g.attrs["n"] >= g.attrs["reach"]
+        # the vmap local stage relaxes min(bucket, k) fragment blocks
+        assert g.attrs["frags"] == min(g.attrs["bucket"], fr.k)
         if g.attrs["kind"] == "reach":
             assert g.attrs["reach"] == g.attrs["n"]
 
@@ -176,6 +178,8 @@ def test_row_counters_equal_the_planners_buckets(fr):
         g.padded_size for g in plan.groups)
     assert sess.stats.reach_rows - before["reach_rows"] == 5
     assert sess.stats.reach_fused - before["reach_fused"] == 5
+    # the dist group's 16 rows relax all 4 fragments once; RPQ none
+    assert sess.stats.frag_blocks - before["frag_blocks"] == fr.k == 4
 
 
 def _kernel_args(fr, kind):
