@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache for long runs.
 
 Entry points that compile for minutes (``chip_smoke.py``,
-``benchmarks/run.py``) call :func:`use_compile_cache` first thing; the
+``bench/run.py``) call :func:`use_compile_cache` first thing; the
 library itself never does, so importing :mod:`repro` leaves JAX's cache
 settings alone.
 """

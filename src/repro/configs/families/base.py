@@ -1,4 +1,4 @@
-"""Cell programs: the unit the dry-run lowers and the roofline reads.
+"""Cell programs: the unit the launch tests lower and compile.
 
 A *cell* is one (architecture x input-shape) combination.  Each family
 adapter builds a ``CellProgram``: a step function, abstract (ShapeDtypeStruct)

@@ -23,23 +23,15 @@ def _ceil_log2(b: int) -> int:
     return max(1, math.ceil(math.log2(max(b, 2))))
 
 
-def bool_closure(D, use_pallas="auto"):
+def bool_closure(D):
     """Reflexive-transitive closure of a Boolean matrix [B, B].
 
-    A := A | A@A, repeated ceil(log2 B) times over A = D | I.
-    ``use_pallas``: True forces the Pallas kernel (interpret mode off-TPU,
-    for tests), False forces the XLA fallback, "auto" dispatches on backend
-    (MXU kernel on TPU, f32 matmul elsewhere).
+    A := A | A@A, repeated ceil(log2 B) times over A = D | I.  The products
+    go through the backend dispatcher ``or_and_matmul`` (MXU kernel on TPU,
+    f32 matmul elsewhere).
     """
+    from ..kernels.bool_matmul import ops as bops
     B = D.shape[-1]
-    if use_pallas == "auto":
-        from ..kernels.bool_matmul import ops as bops
-        matmul = bops.or_and_matmul
-    elif use_pallas:
-        from ..kernels.bool_matmul import ops as bops
-        matmul = bops.bool_matmul
-    else:
-        matmul = lambda a, b: (a.astype(jnp.float32) @ b.astype(jnp.float32)) > 0
     A = D | jnp.eye(B, dtype=bool)
     if B == 0:
         return A
@@ -52,31 +44,24 @@ def bool_closure(D, use_pallas="auto"):
 
     def body(state):
         A, i, _ = state
-        A2 = A | matmul(A, A)
+        A2 = A | bops.or_and_matmul(A, A)
         return A2, i + 1, jnp.any(A2 != A)
 
     A, _, _ = jax.lax.while_loop(cond, body, (A, jnp.int32(0), jnp.bool_(True)))
     return A
 
 
-def tropical_closure(W, use_pallas="auto", row_chunk: int = 16):
+def tropical_closure(W):
     """Min-plus closure of a distance matrix [B, B] (diag forced to 0).
 
-    W := min(W, W (min,+) W), repeated ceil(log2 B) times.
-    The pure-jnp path chunks rows (``row_chunk`` of them at a time) so the
-    broadcast intermediate stays at row_chunk * B^2 int32, not B^3.
-    ``use_pallas`` semantics as in :func:`bool_closure`.
+    W := min(W, W (min,+) W), repeated ceil(log2 B) times.  The products
+    go through the backend dispatcher ``min_plus_matmul`` (Pallas kernel
+    on TPU; elsewhere a fallback that chunks rows so the broadcast
+    intermediate stays at ``ROW_CHUNK`` * B^2 int32, not B^3).
     """
+    from ..kernels.tropical_matmul import ops as tops
     B = W.shape[-1]
     W = jnp.where(jnp.eye(B, dtype=bool), 0, W).astype(jnp.int32)
-
-    from ..kernels.tropical_matmul import ops as tops
-    if use_pallas == "auto":
-        mp = lambda a, b: tops.min_plus_matmul(a, b, row_chunk=row_chunk)
-    elif use_pallas:
-        mp = tops.tropical_matmul
-    else:
-        mp = lambda a, b: tops.min_plus_chunked(a, b, row_chunk=row_chunk)
 
     if B == 0:
         return W
@@ -87,7 +72,7 @@ def tropical_closure(W, use_pallas="auto", row_chunk: int = 16):
 
     def body(state):
         W, i, _ = state
-        W2 = jnp.minimum(jnp.minimum(W, mp(W, W)), INF)
+        W2 = jnp.minimum(jnp.minimum(W, tops.min_plus_matmul(W, W)), INF)
         return W2, i + 1, jnp.any(W2 != W)
 
     W, _, _ = jax.lax.while_loop(cond, body, (W, jnp.int32(0), jnp.bool_(True)))

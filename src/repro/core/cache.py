@@ -136,8 +136,8 @@ def _boundary_rows(fr: Fragmentation, frontiers, fill, combine):
     return out[: fr.n_boundary]
 
 
-def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
-                        use_pallas="auto") -> RvsetCache:
+def prepare_rvset_cache(fr: Fragmentation,
+                        with_dist: bool = False) -> RvsetCache:
     """Build (or extend) the amortized cache and attach it to ``fr``."""
     cache = fr.rvset_cache
     if cache is None:
@@ -148,7 +148,7 @@ def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
         bl = _boundary_rows(fr, front, False, lambda ref, v: ref.max(v))
         D0 = _gather_boundary_matrix(fr, bl, fill=False)
         with tracing.span("repro.cache.closure", kind="reach"):
-            C = bes.bool_closure(D0, use_pallas=use_pallas)
+            C = bes.bool_closure(D0)
         cache = RvsetCache(fr=fr, arrays=arrs, bl_frontier=bl, closure=C,
                            part_b=fr.boundary_owner())
         fr.rvset_cache = cache
@@ -160,8 +160,7 @@ def prepare_rvset_cache(fr: Fragmentation, with_dist: bool = False,
         W0 = _gather_boundary_matrix(fr, bl_d, fill=INF)
         cache.bl_dist = bl_d
         with tracing.span("repro.cache.closure", kind="dist"):
-            cache.dist_closure = bes.tropical_closure(W0,
-                                                      use_pallas=use_pallas)
+            cache.dist_closure = bes.tropical_closure(W0)
     return cache
 
 
@@ -597,8 +596,7 @@ def _qa_key(qa: QueryAutomaton) -> Tuple:
     return qa.cache_key()
 
 
-def product_closure(fr: Fragmentation, qa: QueryAutomaton,
-                    use_pallas="auto") -> jax.Array:
+def product_closure(fr: Fragmentation, qa: QueryAutomaton) -> jax.Array:
     """Query-independent product-automaton closure [(nb*Q), (nb*Q)].
 
     Sound because the Glushkov automaton's u_s has no incoming and u_t no
@@ -636,7 +634,7 @@ def product_closure(fr: Fragmentation, qa: QueryAutomaton,
                                 "tgt_local", "labels", "gids")))
         nb = fr.n_boundary
         D = D[:nb * Q, :nb * Q]          # the boundary rows/cols: a prefix
-        C = bes.bool_closure(D, use_pallas=use_pallas)
+        C = bes.bool_closure(D)
     # bound the per-automaton cache: each closure is (nb*Q)^2 bools, and a
     # server facing user-supplied regexes must not grow without limit.
     # dict order is recency order (hits re-insert at the MRU end), so the

@@ -96,20 +96,18 @@ def touched_arrays(report) -> set:
 
 
 def rebuild_cache(fr: Fragmentation, old_version: int, report,
-                  with_dist: bool, use_pallas="auto",
-                  reason: str = "") -> UpdateStats:
+                  with_dist: bool, reason: str = "") -> UpdateStats:
     """Drop + rebuild the cache from the current fragmentation state.
     Snapshot ids stay monotone across rebuilds (QueryServer stamps answers
     with ``cache.version``).  Shared by the host and sharded update paths."""
     fr.rvset_cache = None
-    fresh = prepare_rvset_cache(fr, with_dist=with_dist,
-                                use_pallas=use_pallas)
+    fresh = prepare_rvset_cache(fr, with_dist=with_dist)
     fresh.version = old_version + 1
     return UpdateStats(mode="rebuild", reason=reason, **_stats_base(report))
 
 
 def apply_delta(fr: Fragmentation, delta: GraphDelta,
-                use_pallas="auto", chaos=None) -> UpdateStats:
+                chaos=None) -> UpdateStats:
     """Apply ``delta`` to ``fr`` and incrementally repair its rvset cache.
 
     The attached cache (if any) answers identically to one rebuilt from
@@ -134,7 +132,7 @@ def apply_delta(fr: Fragmentation, delta: GraphDelta,
         return UpdateStats(mode="structural", **base)
     if report.rebuilt:
         return rebuild_cache(fr, cache.version, report, with_dist,
-                             use_pallas, reason=report.reason)
+                             reason=report.reason)
 
     dirty_frac = float(report.dirty.mean())
     if report.n_del:
@@ -142,17 +140,17 @@ def apply_delta(fr: Fragmentation, delta: GraphDelta,
         if cache.repair_debt >= REBUILD_DEBT:
             fr.rebuild()
             return rebuild_cache(fr, cache.version, report, with_dist,
-                                 use_pallas, reason="repair debt")
-        _recompute(cache, report.dirty, warm=False, use_pallas=use_pallas)
+                                 reason="repair debt")
+        _recompute(cache, report.dirty, warm=False)
         cache.refresh_device_arrays(touched_arrays(report))
         return UpdateStats(mode="recompute", **base)
     if dirty_frac > RECOMPUTE_DIRTY_FRAC:
         # insert-only but wide: the changed-row block is most of the matrix,
         # so a (warm-started) recompute is cheaper than the rank update
-        _recompute(cache, report.dirty, warm=True, use_pallas=use_pallas)
+        _recompute(cache, report.dirty, warm=True)
         cache.refresh_device_arrays(touched_arrays(report))
         return UpdateStats(mode="recompute", **base)
-    changed = _repair_insert(cache, report.dirty, use_pallas=use_pallas)
+    changed = _repair_insert(cache, report.dirty)
     cache.refresh_device_arrays(touched_arrays(report))
     return UpdateStats(mode="repair", changed_rows=changed, **base)
 
@@ -270,7 +268,7 @@ def _rank_update_tropical(Cd, rows_new, idx):
     return jnp.minimum(Cd, via)
 
 
-def _repair_insert(cache, dirty: np.ndarray, use_pallas="auto") -> int:
+def _repair_insert(cache, dirty: np.ndarray) -> int:
     """Insert-only repair: warm frontier resume + rank-style closure update.
 
     The candidate rows (every in-node of a dirty fragment) are diffed
@@ -278,9 +276,7 @@ def _repair_insert(cache, dirty: np.ndarray, use_pallas="auto") -> int:
     *actually changed* go through the closure update — in a dense fragment
     most insertions change few or no boundary rows, so the common case is
     a cheap frontier resume and a no-op (or tiny) rank update.  Returns the
-    number of changed D0 rows pushed through the closure.  (The jitted rank
-    updates always use the backend dispatchers — the ``use_pallas`` escape
-    hatch only steers the recompute/rebuild paths.)"""
+    number of changed D0 rows pushed through the closure."""
     fr = cache.fr
     bl_old, bl_d_old = cache.bl_frontier, cache.bl_dist
     _update_frontiers(cache, dirty, warm=True)
@@ -310,7 +306,7 @@ def _repair_insert(cache, dirty: np.ndarray, use_pallas="auto") -> int:
     return int(sel.size)
 
 
-def _recompute(cache, dirty: np.ndarray, warm: bool, use_pallas="auto"):
+def _recompute(cache, dirty: np.ndarray, warm: bool):
     """Per-fragment recompute: refresh dirty fragments' frontiers (cold
     when deletions are present — the old state over-approximates), then
     rebuild D0 by gather and re-close it.  Clean fragments' frontier rows —
@@ -318,7 +314,7 @@ def _recompute(cache, dirty: np.ndarray, warm: bool, use_pallas="auto"):
     fr = cache.fr
     _update_frontiers(cache, dirty, warm=warm)
     D0 = _gather_boundary_matrix(fr, cache.bl_frontier, fill=False)
-    cache.closure = bes.bool_closure(D0, use_pallas=use_pallas)
+    cache.closure = bes.bool_closure(D0)
     if cache.bl_dist is not None:
         W0 = _gather_boundary_matrix(fr, cache.bl_dist, fill=INF)
-        cache.dist_closure = bes.tropical_closure(W0, use_pallas=use_pallas)
+        cache.dist_closure = bes.tropical_closure(W0)

@@ -4,6 +4,13 @@ bit-packed or-and (``bitpack_ops``)."""
 import jax
 
 
+def on_tpu() -> bool:
+    """The one backend switch: on a TPU the semiring dispatchers run the
+    Pallas kernels compiled; elsewhere they take the XLA fallbacks, and a
+    kernel called directly runs in Pallas interpret mode."""
+    return jax.default_backend() == "tpu"
+
+
 def out_vma(*operands) -> frozenset:
     """The mesh axes a kernel's output varies over: those its operands
     vary over.  Inside ``shard_map`` (which checks this) a ``pallas_call``

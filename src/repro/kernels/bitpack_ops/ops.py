@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import on_tpu
 from .bitpack_ops import (bitpack_matmul_pallas, pack_cols, pack_rows,
                           unpack_rows)
 
@@ -24,7 +25,7 @@ def bitpack_bool_matmul(a: jax.Array, b: jax.Array,
     ap = jnp.pad(ap, ((0, pm), (0, pw)))
     bp = jnp.pad(bp, ((0, pw), (0, pn)))
     out = bitpack_matmul_pallas(ap, bp, bm=block, bn=block, bw=bw,
-                                interpret=jax.default_backend() != "tpu")
+                                interpret=not on_tpu())
     return out[:M, :N]
 
 

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import on_tpu
 from .bool_matmul import bool_matmul_pallas
 
 
@@ -17,10 +18,6 @@ def _pad_to(x, m0, m1):
     return x
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block",))
 def bool_matmul(a: jax.Array, b: jax.Array, block: int = 128) -> jax.Array:
     """Or-and matmul with automatic padding; interpret=True off-TPU."""
@@ -29,7 +26,7 @@ def bool_matmul(a: jax.Array, b: jax.Array, block: int = 128) -> jax.Array:
     a = _pad_to(a.astype(bool), bm, bk)
     b = _pad_to(b.astype(bool), bk, bn)
     out = bool_matmul_pallas(a, b, bm=bm, bn=bn, bk=bk,
-                             interpret=not _on_tpu())
+                             interpret=not on_tpu())
     return out[:M, :N]
 
 
@@ -41,6 +38,6 @@ def or_and_matmul(a: jax.Array, b: jax.Array, block: int = 128) -> jax.Array:
     matmul + threshold (interpret-mode Pallas would be orders of magnitude
     slower on CPU, so it is reserved for the kernel unit tests).
     """
-    if _on_tpu():
+    if on_tpu():
         return bool_matmul(a, b, block=block)
     return (a.astype(jnp.float32) @ b.astype(jnp.float32)) > 0

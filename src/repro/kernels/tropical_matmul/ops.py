@@ -6,7 +6,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import on_tpu
 from .tropical_matmul import INF, tropical_matmul_pallas
+
+# rows of ``a`` per step of the fallback: caps its broadcast intermediate
+# at ROW_CHUNK * K * N int32
+ROW_CHUNK = 16
 
 
 def _pad_to(x, m0, m1):
@@ -23,12 +28,11 @@ def tropical_matmul(a: jax.Array, b: jax.Array, block: int = 128) -> jax.Array:
     a = _pad_to(a.astype(jnp.int32), block, block)
     b = _pad_to(b.astype(jnp.int32), block, block)
     out = tropical_matmul_pallas(a, b, bm=block, bn=block, bk=block,
-                                 interpret=jax.default_backend() != "tpu")
+                                 interpret=not on_tpu())
     return out[:M, :N]
 
 
-def min_plus_chunked(a: jax.Array, b: jax.Array,
-                     row_chunk: int = 16) -> jax.Array:
+def min_plus_chunked(a: jax.Array, b: jax.Array) -> jax.Array:
     """Pure-jnp row-chunked (min, +) contraction: chunking caps the
     [C, K, N] broadcast intermediate so the closure of a few-thousand-node
     boundary stays well under a GiB.  The single shared fallback for every
@@ -42,20 +46,20 @@ def min_plus_chunked(a: jax.Array, b: jax.Array,
     def one_chunk(rows):
         return jnp.min(rows[:, :, None] + b[None, :, :], axis=1)
 
-    if M <= row_chunk:
+    if M <= ROW_CHUNK:
         return jnp.minimum(one_chunk(a), INF)
-    pad = (-M) % row_chunk
+    pad = (-M) % ROW_CHUNK
     if pad:
         a = jnp.pad(a, ((0, pad), (0, 0)), constant_values=int(INF))
-    chunks = a.reshape(-1, row_chunk, K)
+    chunks = a.reshape(-1, ROW_CHUNK, K)
     out = jax.lax.map(one_chunk, chunks)
     return jnp.minimum(out.reshape(-1, b.shape[1])[:M], INF)
 
 
-def min_plus_matmul(a: jax.Array, b: jax.Array, block: int = 128,
-                    row_chunk: int = 16) -> jax.Array:
+def min_plus_matmul(a: jax.Array, b: jax.Array,
+                    block: int = 128) -> jax.Array:
     """Backend-dispatched (min, +) contraction C = min_k (a + b):
     the Pallas tropical kernel on TPU, :func:`min_plus_chunked` elsewhere."""
-    if jax.default_backend() == "tpu":
+    if on_tpu():
         return tropical_matmul(a, b, block=block)
-    return min_plus_chunked(a, b, row_chunk=row_chunk)
+    return min_plus_chunked(a, b)

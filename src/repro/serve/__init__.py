@@ -29,7 +29,7 @@ Public surface (everything here is re-exported at this level):
   constants ``GREEN`` / ``YELLOW`` / ``RED`` / ``LANES`` — cost-based
   admission control.
 * :class:`FaultInjector` / :class:`FaultSpec` / ``SITES`` — seeded fault
-  injection for chaos tests and benchmarks.
+  injection for chaos tests.
 * the typed error taxonomy (:class:`ServingError` and subclasses).
 * :class:`Request` / :class:`ServeEngine` — the unrelated toy LM decode
   loop (:mod:`repro.serve.lm`), kept at its historical import path.

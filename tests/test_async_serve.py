@@ -225,6 +225,26 @@ def test_telemetry_reflects_served_load():
         assert 0.0 <= r["p50_ms"] <= r["p95_ms"] <= r["p99_ms"]
 
 
+def test_telemetry_routes_cover_each_served_kind():
+    """Latency is kept per route (``kind/lane``): a mixed reach + dist load
+    shows up under at least one route of each kind, each counting exactly
+    the queries of its kind."""
+    g, fr = _case(24, 70, 2, seed=8)
+    kinds = ["dist" if i % 2 else "reach" for i in range(10)]
+    with QueryServer(fr, batch_size=4, batch_wait_ms=1.0) as srv:
+        futs = [srv.submit(i % g.n, (i * 5) % g.n, kind=kind)
+                for i, kind in enumerate(kinds)]
+        for f in futs:
+            f.result(timeout=RESULT_TIMEOUT_S)
+        routes = srv.telemetry()["routes"]
+    assert len(routes) >= 2, routes
+    per_kind = {}
+    for route, r in routes.items():
+        kind = route.split("/")[0]
+        per_kind[kind] = per_kind.get(kind, 0) + r["count"]
+    assert per_kind == {"reach": 5, "dist": 5}, routes
+
+
 def test_submit_after_close_is_refused():
     g, fr = _case(10, 20, 2, seed=0)
     srv = QueryServer(fr, batch_size=4, warm=False, start=False)

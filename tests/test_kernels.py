@@ -62,15 +62,22 @@ def test_pack_roundtrip(k):
                                   np.asarray(a))
 
 
-def test_closure_with_pallas_matches_ref():
-    """End-to-end: bes closures using the kernels == pure-jnp closures."""
+def test_closure_with_pallas_matches_ref(monkeypatch):
+    """End-to-end: bes closures using the kernels == pure-jnp closures.
+    Off-TPU the dispatchers take the XLA fallbacks; pointing them at the
+    Pallas kernels (interpret mode here) runs the closures as on a TPU."""
+    import repro.kernels.bool_matmul.ops as bops
+    import repro.kernels.tropical_matmul.ops as tops
     from repro.core.bes import bool_closure, tropical_closure
     rng = np.random.default_rng(0)
     D = jnp.asarray(rng.random((50, 50)) < 0.05)
-    np.testing.assert_array_equal(np.asarray(bool_closure(D, use_pallas=True)),
-                                  np.asarray(bool_closure(D)))
     W = rng.integers(0, 9, (40, 40)).astype(np.int32)
     W[rng.random((40, 40)) < 0.6] = int(INF)
-    got = tropical_closure(jnp.asarray(W), use_pallas=True)
-    want = tropical_closure(jnp.asarray(W))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    want_d = bool_closure(D)
+    want_w = tropical_closure(jnp.asarray(W))
+    monkeypatch.setattr(bops, "or_and_matmul", bops.bool_matmul)
+    monkeypatch.setattr(tops, "min_plus_matmul", tops.tropical_matmul)
+    np.testing.assert_array_equal(np.asarray(bool_closure(D)),
+                                  np.asarray(want_d))
+    got = tropical_closure(jnp.asarray(W))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want_w))

@@ -1,9 +1,8 @@
 """Launch layer: HLO collective parsing + mini dry-run on a 4x4 fake mesh.
 
-The full 512-device dry-run is exercised by ``repro.launch.dryrun`` (see
-results/dryrun.json); here we keep a fast structural test that the cell
-programs lower+compile with their shardings on a small mesh, in a
-subprocess so the fake device count never leaks into other tests.
+A fast structural test that the cell programs lower+compile with their
+shardings on a small mesh, in a subprocess so the fake device count never
+leaks into other tests.
 """
 import json
 import os
